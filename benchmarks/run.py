@@ -142,10 +142,12 @@ def main():
             "BENCH_quick.json")
 
     from repro import obs
+    from repro.runtime.compile_cache import use_compile_cache
 
     from . import (bench_build, bench_datapath, bench_knn, bench_serving,
                    bench_sweep, bench_traversal)
 
+    use_compile_cache()
     obs.enable()  # every row gets its section's telemetry slice
 
     rows: list[tuple] = []

@@ -23,6 +23,7 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 from render import ground_plane, icosphere  # noqa: E402  (sibling example)
 
 from repro.api import Scene, Triangle, make_ray  # noqa: E402
+from repro.runtime.compile_cache import use_compile_cache  # noqa: E402
 
 
 def build_soup():
@@ -65,6 +66,7 @@ def camera_rays(res):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--frames", type=int, default=8)
     ap.add_argument("--res", type=int, default=64)

@@ -18,9 +18,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.api import PointCloudScene, VectorIndex
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     rng = np.random.default_rng(0)
     n_db, n_q, dim = 8192, 64, 128
     # clustered database so neighbours are meaningful

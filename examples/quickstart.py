@@ -9,9 +9,11 @@ from repro.api import Scene, VectorIndex, trace_backends
 from repro.core import (OP_ANGULAR, OP_EUCLIDEAN, OP_QUADBOX, OP_TRIANGLE,
                         Box, Triangle, make_ray, unified_stream)
 from repro.core.stream import make_jobs
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     print("== OpQuadbox: one ray vs four AABBs ==")
     jobs = make_jobs(4)
     ray = make_ray(jnp.asarray([[-2.0, 0.5, 0.5]] * 4),

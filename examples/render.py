@@ -30,6 +30,7 @@ import numpy as np
 
 from repro.api import Scene, Triangle, make_ray, trace_backends
 from repro.core.session import trace_backend_ray_types
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def icosphere(subdiv=3):
@@ -75,6 +76,7 @@ def build_scene():
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("out", nargs="?", default="/tmp/render.pgm")
     # only backends that can serve the shadow pass are offered (per_ray

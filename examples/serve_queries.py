@@ -33,6 +33,7 @@ import numpy as np
 
 from repro import obs
 from repro.api import PointCloudScene, QueryEngine, Scene, make_ray
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serving import QueryServer
 
 TRACE_FIELDS = ("t", "tri_index", "hit", "quadbox_jobs", "triangle_jobs")
@@ -189,6 +190,7 @@ async def main_async(args):
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--users", type=int, default=12)
     ap.add_argument("--requests", type=int, default=4,

@@ -19,6 +19,7 @@ from repro.models import ModelConfig, count_params, init_params
 from repro.optim import adamw
 from repro.parallel.ctx import NO_PARALLEL as ctx
 from repro.runtime import Supervisor, SupervisorConfig
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serving import Engine
 from repro.train import make_train_step
 
@@ -38,6 +39,7 @@ def model_small():
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

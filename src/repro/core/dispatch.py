@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..parallel.compat import make_device_mesh, shard_map_unchecked
 from ..parallel.sharding import batch_sharded, replicated  # noqa: F401
 
 #: mesh axis name carrying the data-parallel ray/query batch
@@ -104,7 +103,9 @@ def device_mesh(shards: int, axis_name: str = BATCH_AXIS) -> Mesh:
     key = (axis_name, shards)
     mesh = _MESHES.get(key)
     if mesh is None:
-        mesh = _MESHES[key] = make_device_mesh(shards, axis_name)
+        mesh = _MESHES[key] = jax.make_mesh(
+            (shards,), (axis_name,),
+            axis_types=(jax.sharding.AxisType.Auto,))
     return mesh
 
 
@@ -244,8 +245,8 @@ def shard_rows(fn, mesh: Mesh, axis: str = BATCH_AXIS):
     closed over, replicated).  Every output leaf must carry the row axis
     first — scalar statistics must be lifted to a length-1 axis so they
     come back as one value per shard."""
-    return shard_map_unchecked(fn, mesh, in_specs=(P(axis),),
-                               out_specs=P(axis))
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(axis),),
+                         out_specs=P(axis), check_vma=False)
 
 
 def shard_rows_ctx(fn, mesh: Mesh, axis: str = BATCH_AXIS):
@@ -254,5 +255,5 @@ def shard_rows_ctx(fn, mesh: Mesh, axis: str = BATCH_AXIS):
     prepared form of it — threaded as a runtime argument, not closed
     over, so ``Scene.refit`` swaps its arrays without retracing), the
     second is row-sharded as usual."""
-    return shard_map_unchecked(fn, mesh, in_specs=(P(), P(axis)),
-                               out_specs=P(axis))
+    return jax.shard_map(fn, mesh=mesh, in_specs=(P(), P(axis)),
+                         out_specs=P(axis), check_vma=False)

@@ -743,7 +743,8 @@ class QueryEngine:
     query → unpad round trip is an identity (``tests/test_session.py``).
 
     ``backend="auto"`` picks per query: wavefront for traced batches
-    (per-ray oracle for tiny closest-hit batches), Pallas kernels for
+    (per-ray oracle for tiny closest-hit batches), the tree wavefront for
+    qualifying cloud queries, the Pallas distance kernels for brute
     distance queries on TPU and the MXU jnp form elsewhere.
 
     Two execution knobs ride on every query (``core/dispatch.py``,
@@ -768,12 +769,6 @@ class QueryEngine:
     #: closest-hit batches up to this size go to the per-ray oracle under
     #: "auto" (the batch loop only pays off once the frontier is wide)
     AUTO_PER_RAY_MAX = 8
-
-    #: "auto" routes TPU traces to the fused Pallas kernel only while the
-    #: scene's resident operands (node boxes + leaf table + triangle soup,
-    #: mapped whole into every tile) fit comfortably in VMEM (~16 MB/core);
-    #: past this budget the wavefront engine handles the scene unchanged
-    AUTO_PALLAS_SCENE_BYTES = 8 * 2**20
 
     #: below this cloud size "auto" keeps neighbor queries on the brute
     #: path: one small MXU matmul beats any traversal's pointer chasing
@@ -880,33 +875,18 @@ class QueryEngine:
         """The backend "auto" picks for a trace: per-ray oracle for tiny
         plain closest-hit batches; every other query — including ones the
         oracle cannot express (t_min, max_rounds) and any sharded batch
-        (a multi-device frontier is by definition not tiny) — goes to a
-        batch engine: the fused Pallas traversal kernel on TPU (the loop
-        state stays on-chip) while the scene fits the kernel's on-chip
-        budget, the wavefront engine everywhere else (off-TPU interpret
-        mode would only add overhead; an over-budget tree would overflow
-        VMEM).  All three return bit-identical results, so the policy is
-        pure scheduling."""
+        (a multi-device frontier is by definition not tiny) — goes to the
+        wavefront engine, on every platform.  The fused Pallas traversal
+        kernel is not a route "auto" takes: Mosaic does not lower its
+        per-lane stack pop (``tests/test_tpu_compile.py`` pins the
+        refusal), so it is reachable only as an explicit
+        ``backend="pallas"``.  All three return bit-identical results, so
+        the policy is pure scheduling."""
         if (shards == 1 and ray_type == "closest"
                 and n_rays <= self.AUTO_PER_RAY_MAX
                 and not t_min and max_rounds is None):
             return "per_ray"
-        if (jax.default_backend() == "tpu"
-                and self._scene_resident_bytes() <= self.AUTO_PALLAS_SCENE_BYTES):
-            return "pallas"
         return "wavefront"
-
-    def _scene_resident_bytes(self) -> int:
-        """Bytes the fused traversal kernel keeps resident per tile: node
-        boxes (at the scene config's packed dtype — bf16 configs pack
-        2 B/scalar) + leaf table + triangle soup (f32/i32 = 4 B each)."""
-        if self.scene is None:
-            return 0
-        bvh = self.scene.bvh
-        n_nodes = bvh.node_lo.shape[0]
-        box_bytes = jnp.dtype(self.scene.config.packed_box_dtype).itemsize
-        return (box_bytes * 2 * n_nodes * 3
-                + 4 * (bvh.leaf_tri.shape[0] + 9 * bvh.triangles.a.shape[0]))
 
     def resolve_distance_backend(self) -> str:
         """The backend "auto" picks for distance queries: compiled Pallas
@@ -927,11 +907,11 @@ class QueryEngine:
         selectivity (k/N for nearest; search-ball volume over root-box
         volume for radius queries) exceeds
         :data:`AUTO_TREE_MAX_SELECTIVITY`, the brute matmul wins and
-        "auto" stays on the distance backends.  Otherwise: the fused
-        Pallas neighbor kernel on TPU while the packed cloud fits its
-        on-chip budget, the wavefront neighbor engine everywhere else.
-        Either way every route returns the same in-radius sets and
-        neighbor ranks, so the policy is pure scheduling."""
+        "auto" stays on the distance backends.  Otherwise the wavefront
+        neighbor engine, on every platform (the fused ``tree_pallas``
+        kernel does not lower under Mosaic, so it is explicit-only, like
+        the fused trace kernel).  Every route returns the same in-radius
+        sets and neighbor ranks, so the policy is pure scheduling."""
         if self.cloud is None or metric != "euclidean":
             return self.resolve_distance_backend()
         n = self.cloud.size
@@ -947,21 +927,7 @@ class QueryEngine:
                                          and math.isfinite(ball)) else 1.0
         if selectivity > self.AUTO_TREE_MAX_SELECTIVITY:
             return self.resolve_distance_backend()
-        if (jax.default_backend() == "tpu"
-                and self._cloud_resident_bytes()
-                <= self.AUTO_PALLAS_SCENE_BYTES):
-            return "tree_pallas"
         return "tree_wavefront"
-
-    def _cloud_resident_bytes(self) -> int:
-        """Bytes the fused neighbor kernel keeps resident per tile: node
-        boxes + leaf table + packed point rows (x, y, z, ||c||^2)."""
-        if self.cloud is None:
-            return 0
-        bvh = self.cloud.bvh
-        n_nodes = bvh.node_lo.shape[0]
-        return 4 * (2 * n_nodes * 3 + bvh.leaf_tri.shape[0]
-                    + 4 * bvh.triangles.a.shape[0])
 
     # -- execution planning (sharding + chunking, core/dispatch.py) -------
 
@@ -1374,11 +1340,7 @@ class QueryEngine:
             radius = check_radius(radius, "euclidean")
         name = backend or self.default_backend
         if name == "auto":
-            name = ("tree_pallas"
-                    if (jax.default_backend() == "tpu"
-                        and self._cloud_resident_bytes()
-                        <= self.AUTO_PALLAS_SCENE_BYTES)
-                    else "tree_wavefront")
+            name = "tree_wavefront"
         if name not in _NEIGHBOR_BACKENDS:
             raise ValueError(f"unknown neighbor backend {name!r} "
                              f"(registered: {neighbor_backends()})")
@@ -1395,9 +1357,10 @@ class QueryEngine:
         ``valid`` False) instead of crashing inside ``lax.top_k``.
 
         With a :class:`PointCloudScene`, ``backend="auto"`` routes
-        euclidean queries through the BVH (``tree_wavefront`` /
-        ``tree_pallas``) when the tree wins; the brute backends
-        (``mxu`` / ``pallas``) remain the rank-equivalent oracle."""
+        euclidean queries through the BVH (``tree_wavefront``; the fused
+        ``tree_pallas`` is explicit-only) when the tree wins; the brute
+        backends (``mxu`` / ``pallas``) remain the rank-equivalent
+        oracle."""
         if metric not in METRICS:
             raise ValueError(f"unknown metric: {metric}")
         k = check_k(k)

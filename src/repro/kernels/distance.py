@@ -44,8 +44,11 @@ def _distance_kernel(q_ref, c_ref, out_ref, acc_ref, *, mode: str, nk: int):
 
     q = q_ref[...].astype(jnp.float32)
     c = c_ref[...].astype(jnp.float32)
-    # the shared multiplier array: one MXU pass per beat
+    # the shared multiplier array: one MXU pass per beat.  HIGHEST pins
+    # full-f32 contraction, the precision of the brute oracle
+    # (core/knn.py) this kernel's neighbour ids are checked against
     qc = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     if mode == "euclidean":
         q2 = jnp.sum(q * q, axis=1, keepdims=True)  # (BM, 1)

@@ -32,7 +32,6 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..core.session import VectorIndex
-from ..parallel.compat import shard_map
 from .config import ModelConfig, MoEConfig
 from .layers import dense_init, split
 
@@ -206,7 +205,7 @@ def _moe_ep(cfg: ModelConfig, ctx, p, x_flat, weights, experts):
         return jax.lax.psum(y.astype(cd), model_axis)
 
     tok_spec = P(batch_axes if batch_axes else None, None)
-    out = shard_map(
+    out = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(tok_spec, tok_spec, tok_spec,
                   P(model_axis, None, None), P(model_axis, None, None),

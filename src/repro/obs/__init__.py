@@ -91,9 +91,9 @@ def enable() -> None:
 
 
 def disable() -> None:
-    """Turn recording off.  The compile hook stays installed (removing
-    it would cold-start jax's tracing cache and miscount later), but it
-    only bumps one integer per retrace — stock-jax behavior otherwise."""
+    """Turn recording off.  The compile listener stays registered
+    (``jax.monitoring`` has no per-listener removal), but it only bumps
+    one integer per retrace — stock-jax behavior otherwise."""
     default_registry().disable()
 
 

@@ -2,22 +2,18 @@
 
 The repo's zero-retrace contracts (``Scene.refit`` animation frames,
 chunked dispatch re-entering one compiled function, the serving ladder's
-O(log) program count) were guarded by a test-only closure-counter trick:
-``jax._src.test_util.count_jit_tracing_cache_miss`` monkey-patched
-around each assertion.  This module promotes that trick into a public,
-always-available :class:`CompileTracker` (DESIGN.md §11) so the same
-signal that gates the tests can be *served* — exported in
-``obs.snapshot()``, attached to benchmark rows, and asserted by CI
-against a live serving run.
+O(log) program count) are asserted through :class:`CompileTracker`
+(DESIGN.md §11), and the same signal is *served* — exported in
+``obs.snapshot()``, attached to benchmark rows, and asserted by the
+serving example against a live run.
 
-Mechanism: one process-wide hook around ``jax``'s pjit jaxpr-creation
-step — the function that runs exactly once per (fun, abstract-args)
-tracing-cache miss, i.e. per retrace.  The hook is installed lazily on
-first use and then **never removed**: the wrapper is ``lu.cache``-d like
-the original, so uninstalling/reinstalling would cold-start that cache
-and miscount warm functions as fresh compiles.  Until something installs
-it, tracked totals read 0 and the interpreter runs byte-for-byte stock
-jax (telemetry disabled really is disabled).
+Mechanism: one process-wide listener on jax's public monitoring event
+``/jax/core/compile/jaxpr_trace_duration``, which jax records exactly
+once per (fun, abstract-args) tracing-cache miss of a jitted function,
+i.e. per retrace.  The listener is registered lazily on first use and
+then **never removed** (``jax.monitoring`` keeps listeners for the life
+of the process).  Until something registers it, tracked totals read 0
+and no callback runs (telemetry disabled really is disabled).
 
 :class:`CompileTracker` is a window over the monotonic process total::
 
@@ -31,44 +27,43 @@ increments the ``jit.retraces`` counter there.
 """
 from __future__ import annotations
 
+import threading
 from typing import Optional
+
+import jax
 
 from .metrics import default_registry
 
 __all__ = ["CompileTracker", "hook_installed", "install_hook",
            "total_compiles"]
 
-#: monotonic process-wide retrace count (valid once the hook is in)
+#: the jax monitoring event recorded once per jit tracing-cache miss
+TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+
+#: monotonic process-wide retrace count (valid once the listener is in)
 _COUNT = [0]
 _INSTALLED = False
+_LOCK = threading.Lock()  # retraces can happen on serving threads
 
-#: pre-created so the hook's registry path is one attribute check
+#: pre-created so the listener's registry path is one attribute check
 _RETRACES = default_registry().counter("jit.retraces")
 
 
-def install_hook() -> bool:
-    """Install the retrace-counting hook (idempotent).  Returns whether
-    the hook is active — False only when this jax version lacks the
-    internals, in which case tracked counts stay 0 and every consumer
-    degrades gracefully."""
-    global _INSTALLED
-    if _INSTALLED:
-        return True
-    try:  # jax-internal surface: feature-detect, never hard-require
-        from jax._src import linear_util as lu
-        from jax._src import pjit as pjit_lib
-        original = pjit_lib._create_pjit_jaxpr
-    except (ImportError, AttributeError):
-        return False
-
-    @lu.cache
-    def create_pjit_jaxpr_and_count(*args):
-        _COUNT[0] += 1
+def _on_event(event: str, duration: float, **kwargs) -> None:
+    if event == TRACE_EVENT:
+        with _LOCK:
+            _COUNT[0] += 1
         _RETRACES.inc()
-        return original(*args)
 
-    pjit_lib._create_pjit_jaxpr = create_pjit_jaxpr_and_count
-    _INSTALLED = True
+
+def install_hook() -> bool:
+    """Register the retrace-counting listener (idempotent).  Returns
+    whether it is active (always True: the event is public jax API)."""
+    global _INSTALLED
+    with _LOCK:
+        if not _INSTALLED:
+            jax.monitoring.register_event_duration_secs_listener(_on_event)
+            _INSTALLED = True
     return True
 
 
@@ -77,7 +72,7 @@ def hook_installed() -> bool:
 
 
 def total_compiles() -> int:
-    """Process-wide retraces since the hook went in (0 before)."""
+    """Process-wide retraces since the listener went in (0 before)."""
     return _COUNT[0]
 
 
@@ -87,7 +82,7 @@ class CompileTracker:
     Use as a context manager (the test idiom the suite runs on) or via
     explicit :meth:`start` / :meth:`stop`; :attr:`compiles` is the number
     of jit tracings that happened inside the window.  Constructing a
-    tracker installs the hook if it is not in yet.
+    tracker registers the listener if it is not in yet.
     """
 
     def __init__(self):

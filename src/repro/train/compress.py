@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.compat import shard_map
 
 
 def quantize(x: jax.Array):
@@ -86,7 +85,7 @@ def compressed_crosspod_allreduce(grads_stacked, mesh, pod_axis: str = "pod",
 
     pod_spec = lambda x: P(*([pod_axis] + [None] * (x.ndim - 1)))
     rep_spec = lambda x: P(*([None] * x.ndim))
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tuple(pod_spec(x) for x in flat),
                   tuple(pod_spec(x) for x in eflat)),

@@ -26,27 +26,12 @@ def regen_goldens(request):
 
 
 def make_test_mesh(axis_shape, axis_names):
-    """Version-tolerant mesh construction.
-
-    jax >= 0.5 exposes ``jax.sharding.AxisType`` and ``jax.make_mesh`` grew
-    an ``axis_types=`` keyword; on 0.4.x neither exists (every axis is
-    implicitly Auto).  Feature-detect so the multi-device tests run on both.
-    """
+    """A mesh over the first ``prod(axis_shape)`` devices, every axis Auto."""
     import jax
 
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(axis_shape, axis_names,
-                             axis_types=(axis_type.Auto,) * len(axis_names))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(axis_shape, axis_names)
-    import math
-
-    import numpy as np
-
-    n = math.prod(axis_shape)
-    devices = np.asarray(jax.devices()[:n]).reshape(axis_shape)
-    return jax.sharding.Mesh(devices, axis_names)
+    return jax.make_mesh(axis_shape, axis_names,
+                         axis_types=(jax.sharding.AxisType.Auto,)
+                         * len(axis_names))
 
 
 def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600) -> str:
@@ -55,10 +40,13 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600) -> str:
     Multi-device tests need XLA_FLAGS set before jax import, which cannot
     happen inside an already-initialized test process.  The tests directory
     is on the subprocess path so code strings can import helpers from this
-    conftest (``from conftest import make_test_mesh``).
+    conftest (``from conftest import make_test_mesh``).  The child is held
+    to the CPU: forced host devices are CPU devices, and on a machine with
+    an accelerator the parent process may already hold it.
     """
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     # prepend (not replace): packages reachable only via the caller's
     # PYTHONPATH (e.g. hypothesis in some setups) stay importable
     inherited = env.get("PYTHONPATH", "")

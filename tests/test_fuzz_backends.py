@@ -231,7 +231,7 @@ def test_fuzz_datapath_configs_honor_contracts(scene_seed, n_tri, builder,
         exact = DatapathConfig(arity=arity, stack_size=stack_size)
         exact_scene, _ = _config_scene(scene_seed, n_tri, builder, exact)
         ex = trace_wavefront(exact_scene.bvh, rays, exact_scene.depth,
-                             ray_type=ray_type)
+                             ray_type=ray_type, config=exact)
         if ray_type == "closest":  # any-hit walks stop at different nodes
             assert np.all(np.asarray(ref.quadbox_jobs)
                           >= np.asarray(ex.quadbox_jobs)), config.tag

@@ -149,10 +149,10 @@ def test_trace_backend_registry_metadata():
 def test_auto_backend_policy():
     scene, rays = _scene_and_rays(11, 100, 8)
     engine = scene.engine()
-    # off-TPU the batch engine wins; on TPU the fused kernel keeps the
-    # loop state on-chip (all three bit-match, so the policy is pure
-    # scheduling)
-    batch = "pallas" if jax.default_backend() == "tpu" else "wavefront"
+    # every batch trace goes to the wavefront engine, on every platform
+    # (the fused kernel does not lower under Mosaic, so "auto" never
+    # picks it; all three bit-match, so the policy is pure scheduling)
+    batch = "wavefront"
     assert engine.resolve_trace_backend("closest", 4) == "per_ray"
     assert engine.resolve_trace_backend("closest", 500) == batch
     assert engine.resolve_trace_backend("shadow", 4) == batch
@@ -212,20 +212,80 @@ def test_pallas_prepared_ctx_cached_per_version():
 
 
 def test_auto_backend_tpu_routes_to_fused_kernel_within_budget(monkeypatch):
-    """On TPU, "auto" batch traces go to the fused Pallas kernel — but
-    only while the scene's resident operands (mapped whole into every
-    kernel tile) fit the on-chip budget; past it the wavefront engine
-    keeps serving the scene unchanged."""
+    """On TPU, "auto" routes only to kernels that compile for the chip:
+    batch traces go to the wavefront engine whatever the scene's size
+    (the fused traversal kernel, which Mosaic refuses, is never an auto
+    route and there is no on-chip budget left to gate it), qualifying
+    cloud queries to ``tree_wavefront``, and brute distance queries to
+    the Pallas distance kernels, which do compile."""
+    from repro.api import PointCloudScene
+
     scene, _ = _scene_and_rays(11, 100, 8)
     engine = scene.engine()
-    assert engine._scene_resident_bytes() > 0
-    assert QueryEngine(index=None)._scene_resident_bytes() == 0
+    cloud = PointCloudScene.from_points(
+        jax.random.uniform(jax.random.PRNGKey(3), (5000, 3))).engine(shard=1)
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert engine.resolve_trace_backend("closest", 500) == "pallas"
-    assert engine.resolve_trace_backend("shadow", 4) == "pallas"
-    assert engine.resolve_trace_backend("closest", 4) == "per_ray"  # tiny
-    monkeypatch.setattr(engine, "AUTO_PALLAS_SCENE_BYTES", 0)
     assert engine.resolve_trace_backend("closest", 500) == "wavefront"
+    assert engine.resolve_trace_backend("shadow", 4) == "wavefront"
+    assert engine.resolve_trace_backend("any", 1 << 21) == "wavefront"
+    assert engine.resolve_trace_backend("closest", 4) == "per_ray"  # tiny
+    assert engine.resolve_distance_backend() == "pallas"
+    assert cloud.resolve_neighbor_backend("nearest", "euclidean",
+                                          k=16) == "tree_wavefront"
+    assert cloud.resolve_neighbor_backend("within", "euclidean",
+                                          radius=0.01) == "tree_wavefront"
+    assert cloud.resolve_neighbor_backend("nearest", "euclidean",
+                                          k=5000) == "pallas"  # brute
+    # batch sizing follows the route: no fused kernel's 128-lane tile
+    assert cloud.batch_multiple("nearest", k=16) == cloud.pad_multiple
+    assert engine.batch_multiple("trace") == engine.pad_multiple
+
+
+@pytest.mark.parametrize("method", ["trace", "neighbor_search", "nearest"])
+def test_auto_backend_tpu_never_resolves_to_fused_kernels(monkeypatch,
+                                                          method):
+    """Whichever entry point resolves "auto" on TPU, the chosen backend is
+    never the fused trace kernel or ``tree_pallas``; the engine cache key
+    records the backend each call actually ran."""
+    from repro.api import PointCloudScene
+
+    if method == "trace":
+        scene, rays = _scene_and_rays(11, 100, 300)
+        engine = scene.engine(shard=1)
+        call = lambda: engine.trace(rays)  # noqa: E731
+    else:
+        pts = jax.random.uniform(jax.random.PRNGKey(4), (5000, 3))
+        engine = PointCloudScene.from_points(pts).engine(shard=1)
+        q = pts[:300] + 1e-3
+        call = ((lambda: engine.neighbor_search(q, 8, radius=0.05))
+                if method == "neighbor_search"
+                else (lambda: engine.nearest(q, 8)))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    call()
+    used = {key[1] for key in engine._cache if key[0] != "prepare"}
+    assert used and used.isdisjoint({"pallas", "tree_pallas"}), used
+
+
+@pytest.mark.parametrize("backend", ["pallas", "tree_pallas"])
+def test_explicit_fused_backend_on_tpu_compiles_for_real(monkeypatch,
+                                                         backend):
+    """An explicit fused-kernel request on TPU compiles with Mosaic and
+    surfaces the compiler's error; it never drops to interpret mode.
+    With the platform reported as TPU on this CPU host, the real compile
+    path is the one CPU refuses ("Only interpret mode ...")."""
+    from repro.api import PointCloudScene
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if backend == "pallas":
+        scene, rays = _scene_and_rays(11, 100, 8)
+        call = lambda: scene.engine(shard=1).trace(  # noqa: E731
+            rays, backend="pallas")
+    else:
+        pts = jax.random.uniform(jax.random.PRNGKey(5), (64, 3))
+        call = lambda: PointCloudScene.from_points(pts).engine(  # noqa: E731
+            shard=1).neighbor_search(pts[:8], 4, backend="tree_pallas")
+    with pytest.raises(ValueError, match="interpret mode"):
+        call()
 
 
 # ---------------------------------------------------------------------------
