@@ -188,22 +188,17 @@ def make_plan(n: int, *, pad_multiple: int, shards: int = 1,
     return ExecPlan(n=n, block=block, n_blocks=-(-n // block), shards=shards)
 
 
-def split_blocks(tree, plan: ExecPlan):
-    """Yield the plan's padded (and, on a mesh, batch-sharded) blocks.
-
-    Every yielded block has exactly ``plan.block`` rows — the last one
-    padded by repeating its own row 0 — so all blocks re-enter one
-    compiled function.
-    """
-    mesh = plan.mesh
-    for i in range(plan.n_blocks):
-        lo = i * plan.block
-        chunk = jax.tree_util.tree_map(
-            lambda x: x[lo:lo + plan.block], tree)
-        chunk = pad_leading(chunk, plan.block)
-        if mesh is not None:
-            chunk = batch_sharded(mesh, chunk, BATCH_AXIS)
-        yield chunk
+def take_block(tree, plan: ExecPlan, i: int):
+    """Block ``i`` of the plan: ``plan.block`` rows of ``tree`` from row
+    ``i * plan.block`` — the last block padded by repeating its own row 0 —
+    and, on a mesh, batch-sharded; so all blocks re-enter one compiled
+    function."""
+    lo = i * plan.block
+    chunk = jax.tree_util.tree_map(lambda x: x[lo:lo + plan.block], tree)
+    chunk = pad_leading(chunk, plan.block)
+    if plan.shards > 1:
+        chunk = batch_sharded(plan.mesh, chunk, BATCH_AXIS)
+    return chunk
 
 
 def slice_rows(tree, sizes):

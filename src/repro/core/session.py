@@ -45,15 +45,16 @@ the engine remains swappable.
 """
 from __future__ import annotations
 
+import functools
 import math
-import time
 from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 
+from ..obs import programs as _obs_programs
 from ..obs.metrics import default_registry as _obs_registry
-from ..obs.trace import annotate as _obs_annotate
+from ..obs.trace import span
 from .build import build as build_structure
 from .build import refit as refit_bvh
 from .build import tree_stats
@@ -69,7 +70,7 @@ from .dispatch import (
     resolve_shards,
     shard_rows,
     shard_rows_ctx,
-    split_blocks,
+    take_block,
 )
 from .knn import (
     METRICS,
@@ -118,7 +119,10 @@ __all__ = [
 # Telemetry (DESIGN.md §11): instruments are resolved once at import so the
 # recording sites are pre-bound; with the registry disabled (the default)
 # every site below is one attribute check + branch and records nothing —
-# results are bit-identical either way (tests/test_obs.py pins this).
+# results are bit-identical either way (tests/test_obs.py pins this).  The
+# engine's spans (``engine.<method>``, ``engine.prepare``, ``engine.block``,
+# ``engine.assemble``) do not depend on the registry: they are profiler
+# annotations, one native flag check each while no profiler records.
 # ---------------------------------------------------------------------------
 
 _OBS = _obs_registry()
@@ -128,6 +132,20 @@ _OBS_ROWS_REAL = _OBS.counter("engine.rows.real")
 _OBS_ROWS_PADDED = _OBS.counter("engine.rows.padded")
 _OBS_CHUNKS = _OBS.counter("engine.chunks")
 _OBS_SHARDS = _OBS.gauge("engine.shards")
+
+
+def _spanned(method):
+    """Run a query method inside an ``engine.<method>`` span: its length is
+    the host time the engine holds the caller, since every block runs
+    asynchronously."""
+    name = "engine." + method.__name__
+
+    @functools.wraps(method)
+    def call(self, *args, **kwargs):
+        with span(name):
+            return method(self, *args, **kwargs)
+
+    return call
 
 
 # ---------------------------------------------------------------------------
@@ -845,26 +863,38 @@ class QueryEngine:
         return fn
 
     def _obs_record(self, method: str, backend: str, plan: ExecPlan,
-                    t0: float, result, jobs=()) -> None:
-        """Record one executed query into the default registry (callers
-        gate on ``_OBS.enabled``): wall time to a per-method histogram —
-        after blocking on the result, so the clock covers device work —
-        real vs padded rows (the pad-waste numerator/denominator in
-        ``obs.snapshot()``), chunk/shard fan-out, a per-(method, backend)
-        call counter, and whatever datapath job totals the backend
-        reports (quadbox/triangle for traces, box/point for neighbor
-        queries)."""
-        jax.block_until_ready(result)
-        dt_ms = (time.perf_counter() - t0) * 1e3
-        _OBS.histogram(f"engine.call_ms.{method}").observe(dt_ms)
+                    jobs=()) -> None:
+        """Record one enqueued query into the default registry (callers
+        gate on ``_OBS.enabled``): real vs padded rows (the pad-waste
+        numerator/denominator in ``obs.snapshot()``), chunk/shard
+        fan-out, a per-(method, backend) call counter, and whatever
+        datapath job totals the backend reports (quadbox/triangle for
+        traces, box/point for neighbor queries).  The job totals are
+        summed on the device; nothing here waits for the result or reads
+        it back, so telemetry does not change how the call runs."""
         _OBS.counter(f"engine.calls.{method}.{backend}").inc()
         _OBS_ROWS_REAL.inc(plan.n)
         _OBS_ROWS_PADDED.inc(plan.block * plan.n_blocks)
         _OBS_CHUNKS.inc(plan.n_blocks)
         _OBS_SHARDS.set(plan.shards)
         for job_name, per_row in jobs:
-            _OBS.counter(f"engine.jobs.{job_name}.{backend}").inc(
-                int(jnp.sum(per_row)))
+            _OBS.counter(f"engine.jobs.{job_name}.{backend}").add_device(
+                per_row)
+
+    @staticmethod
+    def _run_blocks(fn, tree, plan: ExecPlan, *ctx) -> list:
+        """Enqueue ``fn(*ctx, block)`` for every block of ``tree``, each
+        under an ``engine.block`` span that covers the block's slice and
+        pad and the call's enqueue; the program is noted for
+        :func:`repro.obs.programs.op_scopes`."""
+        outs = []
+        for i in range(plan.n_blocks):
+            with span("engine.block", index=i,
+                      rows=min(plan.block, plan.n - i * plan.block)):
+                block = take_block(tree, plan, i)
+                outs.append(fn(*ctx, block))
+        _obs_programs.note(fn, *ctx, block)
+        return outs
 
     # -- backend resolution ----------------------------------------------
 
@@ -1110,6 +1140,7 @@ class QueryEngine:
 
     # -- traversal queries -------------------------------------------------
 
+    @_spanned
     def trace(self, rays, ray_type: str = "closest", *,
               backend: str | None = None, t_min: float | None = None,
               max_rounds: int | None = None, shard=None,
@@ -1119,6 +1150,34 @@ class QueryEngine:
         the legacy ``trace_rays`` / ``trace_wavefront`` entry points —
         whatever ``shard`` / ``chunk_size`` (None = the engine defaults)
         schedule the batch onto."""
+        with span("engine.prepare"):
+            prepared = self._prepare_trace(rays, ray_type, backend, t_min,
+                                           max_rounds, shard, chunk_size)
+        if isinstance(prepared, TraceResult):
+            return prepared  # the empty batch's typed result
+        fn, ctx, plan, name = prepared
+        outs = self._run_blocks(fn, rays, plan, ctx)
+        with span("engine.assemble"):
+            # streamed assembly: per-ray rows concatenate across chunks;
+            # the batch-level round count is the max over chunks and
+            # shards, which equals the single-device value (a ray is
+            # active for exactly quadbox_jobs consecutive rounds wherever
+            # it executes)
+            rounds = jnp.max(jnp.stack(
+                [jnp.max(jnp.atleast_1d(o.rounds)) for o in outs]))
+            rows = concat_rows([o._replace(rounds=None) for o in outs],
+                               plan.n)
+            res = rows._replace(rounds=rounds)
+        if _OBS.enabled:
+            self._obs_record("trace", name, plan,
+                             jobs=(("quadbox", res.quadbox_jobs),
+                                   ("triangle", res.triangle_jobs)))
+        return res
+
+    def _prepare_trace(self, rays, ray_type, backend, t_min, max_rounds,
+                       shard, chunk_size):
+        """Backend, plan, compiled function and context of a trace:
+        ``(fn, ctx, plan, backend name)``, or the typed empty result."""
         if self.scene is None:
             raise ValueError("QueryEngine has no Scene; construct with "
                              "QueryEngine(scene=...) or Scene.engine()")
@@ -1172,23 +1231,7 @@ class QueryEngine:
             return shard_rows_ctx(per_shard, plan.mesh)
 
         fn = self._compiled(key, build_fn)
-        ctx = self._trace_ctx(name, prepare, plan)
-        t0 = time.perf_counter() if _OBS.enabled else 0.0
-        with _obs_annotate("engine.trace"):
-            outs = [fn(ctx, block) for block in split_blocks(rays, plan)]
-            # streamed assembly: per-ray rows concatenate across chunks; the
-            # batch-level round count is the max over chunks and shards, which
-            # equals the single-device value (a ray is active for exactly
-            # quadbox_jobs consecutive rounds wherever it executes)
-            rounds = jnp.max(jnp.stack(
-                [jnp.max(jnp.atleast_1d(o.rounds)) for o in outs]))
-            rows = concat_rows([o._replace(rounds=None) for o in outs], n)
-            res = rows._replace(rounds=rounds)
-        if _OBS.enabled:
-            self._obs_record("trace", name, plan, t0, res,
-                             jobs=(("quadbox", res.quadbox_jobs),
-                                   ("triangle", res.triangle_jobs)))
-        return res
+        return fn, self._trace_ctx(name, prepare, plan), plan, name
 
     def occluded(self, rays, *, t_min: float = SHADOW_T_MIN,
                  backend: str | None = None, shard=None,
@@ -1202,7 +1245,29 @@ class QueryEngine:
 
     def _distance_fn(self, kind: str, queries, metric: str,
                      backend: str | None, statics: tuple, epilogue,
-                     empty, shard=None, chunk_size: int | None = None):
+                     empty, shard=None, chunk_size: int | None = None,
+                     epilogue_scope: str | None = None):
+        """Run a brute distance query: the backend's scores under the
+        ``<kind>.distance`` named scope, then ``epilogue`` (under
+        ``epilogue_scope``, if given) in the same compiled program."""
+        with span("engine.prepare"):
+            prepared = self._prepare_distance(
+                kind, queries, metric, backend, statics, epilogue, shard,
+                chunk_size, epilogue_scope)
+        if prepared is None:  # empty guard: typed empty result
+            return empty()
+        fn, q, plan, name = prepared
+        outs = self._run_blocks(fn, q, plan)
+        with span("engine.assemble"):
+            res = concat_rows(outs, plan.n)
+        if _OBS.enabled:
+            self._obs_record(kind, name, plan)
+        return res
+
+    def _prepare_distance(self, kind, queries, metric, backend, statics,
+                          epilogue, shard, chunk_size, epilogue_scope):
+        """``(fn, queries, plan, backend name)`` of a distance query, or
+        None for an empty batch (nothing compiled)."""
         if self.index is None:
             raise ValueError("QueryEngine has no VectorIndex; construct "
                              "with QueryEngine(index=...) or "
@@ -1217,8 +1282,8 @@ class QueryEngine:
         n = q.shape[0]
         shards = self._resolve_shards(shard, n)  # validates before guard
         chunk_size = check_count("chunk_size", chunk_size)
-        if n == 0:  # empty guard: typed empty result, nothing compiled
-            return empty()
+        if n == 0:
+            return None
         plan = self._plan(n, shards, chunk_size)
         key = ((kind, name, metric, self._index_version()) + statics
                + plan.key + _elem_key(q))
@@ -1227,19 +1292,20 @@ class QueryEngine:
         def build():
             score_fn = build_scores(self._placed_index(plan), metric,
                                     self.interpret)
-            run = lambda qq: epilogue(score_fn(qq))  # noqa: E731
+
+            def run(qq):
+                with jax.named_scope(f"{kind}.distance"):
+                    scores = score_fn(qq)
+                if epilogue_scope is None:
+                    return epilogue(scores)
+                with jax.named_scope(epilogue_scope):
+                    return epilogue(scores)
+
             if plan.shards == 1:
                 return run
             return shard_rows(run, plan.mesh)
 
-        fn = self._compiled(key, build)
-        t0 = time.perf_counter() if _OBS.enabled else 0.0
-        with _obs_annotate("engine.distance"):
-            res = concat_rows(
-                [fn(block) for block in split_blocks(q, plan)], n)
-        if _OBS.enabled:
-            self._obs_record(kind, name, plan, t0, res)
-        return res
+        return self._compiled(key, build), q, plan, name
 
     def _tree_neighbor(self, kind: str, queries, k: int, radius,
                        name: str, shard=None,
@@ -1252,6 +1318,40 @@ class QueryEngine:
             raise ValueError(
                 f"backend {name!r} needs a PointCloudScene; construct "
                 "with QueryEngine(cloud=...) or PointCloudScene.engine()")
+        with span("engine.prepare"):
+            prepared = self._prepare_neighbor(kind, queries, k, radius,
+                                              name, shard, chunk_size)
+        if isinstance(prepared, NeighborRecord):
+            return prepared  # the empty batch's typed result
+        fn, ctx, rays, plan, kk = prepared
+        n = plan.n
+        outs = self._run_blocks(fn, rays, plan, ctx)
+        with span("engine.assemble"):
+            rounds = jnp.max(jnp.stack(
+                [jnp.max(jnp.atleast_1d(o.rounds)) for o in outs]))
+            rec = concat_rows([o._replace(rounds=None) for o in outs], n)
+            rec = rec._replace(rounds=rounds)
+            if kk < k:  # pad the clamped top-k axis back out (k > N)
+                pad = k - kk
+                rec = rec._replace(
+                    dist_sq=jnp.concatenate(
+                        [rec.dist_sq,
+                         jnp.full((n, pad), jnp.inf, jnp.float32)], axis=1),
+                    index=jnp.concatenate(
+                        [rec.index, jnp.full((n, pad), -1, jnp.int32)],
+                        axis=1),
+                    valid=jnp.concatenate(
+                        [rec.valid, jnp.zeros((n, pad), bool)], axis=1))
+        if _OBS.enabled:
+            self._obs_record(kind, name, plan,
+                             jobs=(("box", rec.box_jobs),
+                                   ("point", rec.point_jobs)))
+        return rec
+
+    def _prepare_neighbor(self, kind, queries, k, radius, name, shard,
+                          chunk_size):
+        """``(fn, ctx, query rays, plan, clamped k)`` of a tree-backed
+        neighbor query, or the typed empty result."""
         mode = "nearest" if kind == "nearest" else "within"
         build, lane_multiple, prepare = _NEIGHBOR_BACKENDS[name]
         q = jnp.asarray(queries, jnp.float32)
@@ -1288,30 +1388,7 @@ class QueryEngine:
             return shard_rows_ctx(per_shard, plan.mesh)
 
         fn = self._compiled(key, build_fn)
-        ctx = self._neighbor_ctx(name, prepare, plan)
-        t0 = time.perf_counter() if _OBS.enabled else 0.0
-        with _obs_annotate("engine.neighbor"):
-            outs = [fn(ctx, block) for block in split_blocks(rays, plan)]
-            rounds = jnp.max(jnp.stack(
-                [jnp.max(jnp.atleast_1d(o.rounds)) for o in outs]))
-            rec = concat_rows([o._replace(rounds=None) for o in outs], n)
-            rec = rec._replace(rounds=rounds)
-        if _OBS.enabled:
-            self._obs_record(kind, name, plan, t0, rec,
-                             jobs=(("box", rec.box_jobs),
-                                   ("point", rec.point_jobs)))
-        if kk < k:  # pad the clamped top-k axis back out (k > N)
-            pad = k - kk
-            rec = rec._replace(
-                dist_sq=jnp.concatenate(
-                    [rec.dist_sq,
-                     jnp.full((n, pad), jnp.inf, jnp.float32)], axis=1),
-                index=jnp.concatenate(
-                    [rec.index, jnp.full((n, pad), -1, jnp.int32)],
-                    axis=1),
-                valid=jnp.concatenate(
-                    [rec.valid, jnp.zeros((n, pad), bool)], axis=1))
-        return rec
+        return fn, self._neighbor_ctx(name, prepare, plan), rays, plan, kk
 
     def _resolve_neighbor_name(self, kind: str, metric: str, backend,
                                k=None, radius=None) -> str:
@@ -1326,6 +1403,7 @@ class QueryEngine:
                 "backends for angular/cosine)")
         return name
 
+    @_spanned
     def neighbor_search(self, queries, k: int, radius=None, *,
                         mode: str = "within",
                         backend: str | None = None, shard=None,
@@ -1348,6 +1426,7 @@ class QueryEngine:
         return self._tree_neighbor(kind, queries, k, radius, name,
                                    shard=shard, chunk_size=chunk_size)
 
+    @_spanned
     def nearest(self, queries, k: int, metric: str = "euclidean", *,
                 backend: str | None = None, shard=None,
                 chunk_size: int | None = None) -> NearestResult:
@@ -1380,8 +1459,9 @@ class QueryEngine:
             lambda: NearestResult(jnp.zeros((0, k), jnp.float32),
                                   jnp.zeros((0, k), jnp.int32),
                                   jnp.zeros((0, k), bool)),
-            shard=shard, chunk_size=chunk_size)
+            shard=shard, chunk_size=chunk_size, epilogue_scope="nearest.topk")
 
+    @_spanned
     def within(self, queries, radius: float, k: int,
                metric: str = "euclidean", *,
                backend: str | None = None, shard=None,
@@ -1411,6 +1491,7 @@ class QueryEngine:
                                  jnp.zeros((0, k), bool)),
             shard=shard, chunk_size=chunk_size)
 
+    @_spanned
     def count_within(self, queries, radius: float,
                      metric: str = "euclidean", *,
                      backend: str | None = None, shard=None,
@@ -1435,6 +1516,7 @@ class QueryEngine:
             lambda: jnp.zeros((0,), jnp.int32),
             shard=shard, chunk_size=chunk_size)
 
+    @_spanned
     def scores(self, queries, metric: str = "euclidean", *,
                backend: str | None = None, shard=None,
                chunk_size: int | None = None) -> jax.Array:

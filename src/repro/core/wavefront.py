@@ -112,35 +112,40 @@ def trace_wavefront(bvh: BVH4, rays, depth: int, ray_type: str = "closest",
 
     def body(state):
         stack, sp, t_best, best_tri, n_qb, n_tri, overflow, done, rounds = state
-        active = (sp > 0) & ~done
-
-        # frontier pop (masked compaction: retired rays contribute no jobs)
-        node = jnp.where(active, stack[rows, jnp.maximum(sp - 1, 0)], 0)
-        sp = jnp.where(active, sp - 1, sp)
-        is_leaf_parent = node >= leaf_parent_offset
+        # the named scopes label each stage's device operations in a
+        # profile (repro.obs.programs.op_scopes); they cost nothing at run
+        # time
+        with jax.named_scope("wavefront.pop"):
+            # frontier pop (masked compaction: retired rays add no jobs)
+            active = (sp > 0) & ~done
+            node = jnp.where(active, stack[rows, jnp.maximum(sp - 1, 0)], 0)
+            sp = jnp.where(active, sp - 1, sp)
+            is_leaf_parent = node >= leaf_parent_offset
 
         # ---- one batched box-test job over the whole frontier ---------------
-        boxes = child_boxes(bvh, node, arity)  # (R, arity, lo/hi)
-        qb = ray_box_test(rays, boxes)
+        with jax.named_scope("wavefront.box"):
+            boxes = child_boxes(bvh, node, arity)  # (R, arity, lo/hi)
+            qb = ray_box_test(rays, boxes)
 
         # ---- batched OpTriangle round for the leaf-parent rays --------------
-        leaf_pos = (arity * node[:, None] + 1 - leaf_offset
-                    + jnp.arange(arity, dtype=jnp.int32))
-        leaf_pos = jnp.clip(leaf_pos, 0, bvh.leaf_tri.shape[0] - 1)
-        tri_idx = bvh.leaf_tri[leaf_pos]  # (R, arity), -1 = padded leaf
-        tris = _gather_triangles(bvh.triangles, tri_idx)
-        tr = ray_triangle_test(_tile_ray(rays, arity), tris)
-        t = tr.t_num / tr.t_denom  # external division, as in trace_ray
-        valid = (tr.hit & (tri_idx >= 0) & (t < t_best[:, None])
-                 & (t <= rays.extent[:, None]) & (t >= t_min))
-        t_masked = jnp.where(valid, t, jnp.inf)
-        j = jnp.argmin(t_masked, axis=1)
-        leaf_t = t_masked[rows, j]
-        leaf_better = active & is_leaf_parent & (leaf_t < t_best)
-        t_best = jnp.where(leaf_better, leaf_t, t_best)
-        best_tri = jnp.where(leaf_better, tri_idx[rows, j], best_tri)
-        if ray_type != "closest":  # any-hit: retire on the first accepted hit
-            done = done | leaf_better
+        with jax.named_scope("wavefront.triangle"):
+            leaf_pos = (arity * node[:, None] + 1 - leaf_offset
+                        + jnp.arange(arity, dtype=jnp.int32))
+            leaf_pos = jnp.clip(leaf_pos, 0, bvh.leaf_tri.shape[0] - 1)
+            tri_idx = bvh.leaf_tri[leaf_pos]  # (R, arity), -1 = padded leaf
+            tris = _gather_triangles(bvh.triangles, tri_idx)
+            tr = ray_triangle_test(_tile_ray(rays, arity), tris)
+            t = tr.t_num / tr.t_denom  # external division, as in trace_ray
+            valid = (tr.hit & (tri_idx >= 0) & (t < t_best[:, None])
+                     & (t <= rays.extent[:, None]) & (t >= t_min))
+            t_masked = jnp.where(valid, t, jnp.inf)
+            j = jnp.argmin(t_masked, axis=1)
+            leaf_t = t_masked[rows, j]
+            leaf_better = active & is_leaf_parent & (leaf_t < t_best)
+            t_best = jnp.where(leaf_better, leaf_t, t_best)
+            best_tri = jnp.where(leaf_better, tri_idx[rows, j], best_tri)
+            if ray_type != "closest":  # any-hit: retire on the first hit
+                done = done | leaf_better
 
         # ---- push hit children far-to-near (sort-network output order) ------
         def push_child(i, carry):
@@ -157,10 +162,11 @@ def trace_wavefront(bvh: BVH4, rays, depth: int, ray_type: str = "closest",
             sp = jnp.where(can, sp + 1, sp)
             return stack, sp, overflow
 
-        stack, sp, overflow = jax.lax.fori_loop(
-            0, arity, push_child, (stack, sp, overflow))
-        n_qb = n_qb + active.astype(jnp.int32)
-        n_tri = n_tri + jnp.where(active & is_leaf_parent, arity, 0)
+        with jax.named_scope("wavefront.push"):
+            stack, sp, overflow = jax.lax.fori_loop(
+                0, arity, push_child, (stack, sp, overflow))
+            n_qb = n_qb + active.astype(jnp.int32)
+            n_tri = n_tri + jnp.where(active & is_leaf_parent, arity, 0)
         return (stack, sp, t_best, best_tri, n_qb, n_tri, overflow, done,
                 rounds + 1)
 

@@ -102,6 +102,9 @@ def distance_pallas(q, c, *, mode="euclidean", bm=BM, bn=BN, bk=BK, interpret=No
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        # the kernel's name in a profile, whatever the Python around it
+        # is called (``nearest.distance_share`` matches it)
+        name="distance_pallas",
     )(q, c)
 
 

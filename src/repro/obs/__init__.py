@@ -1,12 +1,13 @@
 """``repro.obs`` — the telemetry plane (DESIGN.md §11).
 
-One switch, three signals, one export surface:
+One switch, three signals, one export surface, and one timeline:
 
 * **Metrics** — :class:`MetricsRegistry` counters / gauges / streaming
-  histograms (``obs/metrics.py``).  The engine records per-call wall
-  time, jit-cache hits vs compiles, pad waste (padded vs real rows),
-  chunk/shard fan-out, and aggregate datapath job counts per backend;
-  the serving layer routes its request accounting through a registry.
+  histograms (``obs/metrics.py``).  The engine records jit-cache hits vs
+  compiles, pad waste (padded vs real rows), chunk/shard fan-out, and
+  aggregate datapath job counts per backend, summed on the device (no
+  call waits for telemetry); the serving layer routes its request
+  accounting through a registry.
 * **Compile events** — :class:`CompileTracker` (``obs/compile.py``): the
   test suite's jit tracing-cache-miss counter promoted to a public
   window over a process-wide retrace count, so "steady-state compiles
@@ -14,12 +15,20 @@ One switch, three signals, one export surface:
 * **Traces** — request-lifecycle spans (admit → coalesce → execute →
   split per served request) in a bounded buffer, exported as
   Chrome-trace/Perfetto JSON (``obs/trace.py``).
+* **Spans on the profiler's clock** — :func:`span` (``obs/trace.py``)
+  is a ``jax.profiler.TraceAnnotation`` whatever the switch says: under
+  ``jax.profiler.start_trace`` the engine's ``engine.*`` and the
+  server's ``serving.*`` spans land beside the device's operations; with
+  no profiler recording each costs one native flag check.  The engine's
+  device programs carry ``jax.named_scope`` s (``wavefront.box``,
+  ``nearest.topk``, ...); :func:`programs.op_scopes` maps each compiled
+  operation to its scope.
 
-Everything is **off by default** and free while off: recording sites
-pre-resolve their instruments and the disabled path is one attribute
-check + branch, so engine and serving results are bit-identical (and
-latency indistinguishable) with telemetry disabled — the contract
-``tests/test_obs.py`` pins.
+The registry and the span buffer are **off by default** and free while
+off: recording sites pre-resolve their instruments and the disabled path
+is one attribute check + branch, so engine and serving results are
+bit-identical with telemetry on or off — the contract ``tests/test_obs.py``
+pins — and turning it on makes no call wait or read the device.
 
 Quickstart::
 
@@ -29,6 +38,10 @@ Quickstart::
     ... run queries / serve traffic ...
     print(obs.snapshot())                    # JSON-able dict
     obs.export_chrome_trace("trace.json")    # open in Perfetto
+
+    jax.profiler.start_trace("/tmp/prof")    # the timeline: engine.* and
+    engine.trace(rays)                       # serving.* spans beside the
+    jax.profiler.stop_trace()                # device ops, by named scope
 
     with obs.CompileTracker() as t:
         engine.trace(rays)                   # warm steady state
@@ -44,7 +57,8 @@ from typing import Callable
 
 from .compile import CompileTracker, hook_installed, install_hook, total_compiles  # noqa: F401
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry, default_registry  # noqa: F401
-from .trace import TraceBuffer, annotate, default_buffer, export_chrome_trace  # noqa: F401
+from . import programs  # noqa: F401
+from .trace import TraceBuffer, default_buffer, export_chrome_trace, profiled_spans, span  # noqa: F401
 
 __all__ = [
     "CompileTracker",
@@ -53,7 +67,6 @@ __all__ = [
     "Histogram",
     "MetricsRegistry",
     "TraceBuffer",
-    "annotate",
     "default_buffer",
     "default_registry",
     "disable",
@@ -61,9 +74,12 @@ __all__ = [
     "export_chrome_trace",
     "install_hook",
     "is_enabled",
+    "profiled_spans",
+    "programs",
     "register_source",
     "registry",
     "snapshot",
+    "span",
     "total_compiles",
     "unregister_source",
     "write_snapshot",

@@ -20,7 +20,10 @@ Design constraints, in order:
    behavior and the engine-result bit-parity on/off).
 2. **Dependency-free.**  Plain Python; histograms are fixed
    log-spaced bins, not a sketch library.
-3. **JSON-able.**  ``MetricsRegistry.snapshot()`` returns nothing but
+3. **No device sync.**  A counter fed device arrays
+   (``Counter.add_device``) sums them on the device; only
+   :meth:`MetricsRegistry.snapshot` reads the total back.
+4. **JSON-able.**  ``MetricsRegistry.snapshot()`` returns nothing but
    dicts / lists / numbers / strings, so it can be dumped, uploaded as a
    CI artifact, and diffed across runs.
 
@@ -57,19 +60,35 @@ HIST_BINS = 64
 
 
 class Counter:
-    """Monotonic counter.  ``inc`` is a no-op while the owning registry
-    is disabled."""
+    """Monotonic counter.  ``inc`` and ``add_device`` are no-ops while the
+    owning registry is disabled."""
 
-    __slots__ = ("name", "_reg", "value")
+    __slots__ = ("name", "_reg", "value", "device")
 
     def __init__(self, name: str, reg: "MetricsRegistry"):
         self.name = name
         self._reg = reg
         self.value = 0
+        self.device = None  # a DeviceTotal once add_device is called
 
     def inc(self, n: int = 1) -> None:
         if self._reg.enabled:
             self.value += n
+
+    def add_device(self, x) -> None:
+        """Add the sum of the integer device array ``x``, summed on the
+        device: nothing waits for it or comes to the host until
+        :meth:`total` reads it."""
+        if self._reg.enabled:
+            if self.device is None:
+                from .device import DeviceTotal
+                self.device = DeviceTotal()
+            self.device.add(x)
+
+    def total(self) -> int:
+        """``value`` plus what ``add_device`` added (read from the
+        device, so this waits for the arrays added)."""
+        return self.value + (0 if self.device is None else int(self.device))
 
     def __repr__(self):
         return f"Counter({self.name}={self.value})"
@@ -205,6 +224,7 @@ class MetricsRegistry:
         stay valid — their values reset in place)."""
         for c in self._counters.values():
             c.value = 0
+            c.device = None
         for g in self._gauges.values():
             g.value = 0.0
         for h in self._histograms.values():
@@ -233,7 +253,7 @@ class MetricsRegistry:
                 "p99": None if h.count == 0 else h.percentile(0.99),
             }
         return {
-            "counters": {n: c.value
+            "counters": {n: c.total()
                          for n, c in sorted(self._counters.items())},
             "gauges": {n: g.value for n, g in sorted(self._gauges.items())},
             "histograms": hists,

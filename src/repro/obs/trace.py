@@ -19,24 +19,30 @@ share a clock by construction; tracks from different subsystems may use
 different clocks, which Chrome renders fine (each track is internally
 ordered — the cross-track offset just isn't meaningful).
 
-``annotate(name)`` additionally scopes a ``jax.profiler.TraceAnnotation``
-around device work when telemetry is enabled, so an active jax profiler
-(``jax.profiler.trace``) shows engine execute windows on the device
-timeline alongside its XLA events; with telemetry off (or no profiler
-machinery) it is a no-op context.
+``span(name, **args)`` is the program's own span on the profiler's
+clock: it always enters a ``jax.profiler.TraceAnnotation``, so under
+``jax.profiler.start_trace`` the span lands on the host plane of the same
+``.xplane.pb`` as the device's operations, and costs one native flag
+check while no profiler records.  While one does, the span is also kept,
+bounded, in :func:`profiled_spans`, so code in the same process (the
+benchmark's readers, the tests) sees it without parsing the trace file.
+It does not depend on :func:`repro.obs.enable`.
 """
 from __future__ import annotations
 
 import json
 import threading
+import time
 from collections import deque
-from contextlib import nullcontext
-from typing import Optional
+from contextlib import contextmanager
+from typing import NamedTuple, Optional
+
+from jax.profiler import TraceAnnotation
 
 from .metrics import default_registry
 
-__all__ = ["Span", "TraceBuffer", "annotate", "default_buffer",
-           "export_chrome_trace"]
+__all__ = ["ProfiledSpan", "Span", "TraceBuffer", "default_buffer",
+           "export_chrome_trace", "profiled_spans", "span"]
 
 #: spans kept in the bounded global buffer (oldest dropped first — a
 #: long-lived server exports windows, not unbounded history)
@@ -156,14 +162,40 @@ def export_chrome_trace(path: str,
     return (buffer or _DEFAULT).export_chrome_trace(path)
 
 
-def annotate(name: str):
-    """A ``jax.profiler.TraceAnnotation`` scope when telemetry is
-    enabled (so an active profiler labels the device work), a no-op
-    context otherwise."""
-    if not default_registry().enabled:
-        return nullcontext()
-    try:
-        import jax.profiler
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # profiler machinery unavailable: stay silent
-        return nullcontext()
+class ProfiledSpan(NamedTuple):
+    """One :func:`span` closed while a profiler recorded: its name, its
+    ``args``, and its start and end on ``time.perf_counter`` (seconds)."""
+
+    name: str
+    start: float
+    end: float
+    args: dict
+
+
+#: spans kept while a profiler records (oldest dropped first)
+MAX_PROFILED = 100_000
+_PROFILED: deque = deque(maxlen=MAX_PROFILED)
+
+
+@contextmanager
+def span(name: str, **args):
+    """A ``jax.profiler.TraceAnnotation`` named ``name`` with ``args``
+    around the block, whatever :func:`repro.obs.enable` says; while a
+    profiler records, the closed span is also kept in
+    :func:`profiled_spans`."""
+    with TraceAnnotation(name, **args):
+        if not TraceAnnotation.is_enabled():
+            yield
+            return
+        start = time.perf_counter()
+        try:
+            yield
+        finally:
+            _PROFILED.append(ProfiledSpan(name, start, time.perf_counter(),
+                                          args))
+
+
+def profiled_spans() -> list:
+    """The :class:`ProfiledSpan` s closed while a profiler recorded, in
+    the order they closed (an enclosing span after the spans it holds)."""
+    return list(_PROFILED)

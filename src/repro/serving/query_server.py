@@ -48,7 +48,7 @@ from ..core.session import QueryEngine
 from ..core.wavefront import RAY_TYPES, SHADOW_T_MIN
 from ..obs import register_source
 from ..obs.metrics import MetricsRegistry
-from ..obs.trace import default_buffer
+from ..obs.trace import default_buffer, span
 from .admission import (
     ADMIT,
     REJECT,
@@ -100,7 +100,7 @@ class _MethodStats:
     the server's registered snapshot source."""
 
     __slots__ = ("requests", "rows", "batches", "batch_rows", "padded_rows",
-                 "flushes", "shed", "latency_ms")
+                 "flushes", "shed", "latency_ms", "queue_ms")
 
     def __init__(self, reg: MetricsRegistry, method: str):
         pre = f"serving.{method}."
@@ -114,6 +114,8 @@ class _MethodStats:
                                        FLUSH_DEADLINE, FLUSH_DRAIN)}
         self.shed = reg.counter(pre + "shed")
         self.latency_ms = reg.histogram(pre + "latency_ms")
+        # enqueue to the start of the batch's execution (worker thread)
+        self.queue_ms = reg.histogram(pre + "queue_ms")
 
 
 def _n_rows(payload) -> int:
@@ -466,16 +468,25 @@ class QueryServer:
         contract; see the module docstring for why it holds."""
         target = self._target_rows(batch)
         t_exec = self._clock()
-        payload = _assemble_payload(batch.requests, target)
-        result = self._call_engine(batch.method, payload,
-                                   dict(batch.params))
-        jax.block_until_ready(result)
         ms = self._mstats(batch.method)
+        for req in batch.requests:
+            ms.queue_ms.observe((t_exec - req.enqueued) * 1e3)
+        # spans on the profiler's clock, beside the engine's own and the
+        # device's operations (repro.obs.span)
+        with span("serving.assemble", method=batch.method, rows=batch.rows,
+                  target=target):
+            payload = _assemble_payload(batch.requests, target)
+        with span("serving.execute", method=batch.method):
+            result = self._call_engine(batch.method, payload,
+                                       dict(batch.params))
+            jax.block_until_ready(result)
         ms.batches.inc()
         ms.batch_rows.inc(batch.rows)
         ms.padded_rows.inc(max(target, batch.rows))
         t_split = self._clock()
-        parts = self._split(batch.method, result, batch.sizes)
+        with span("serving.split", method=batch.method,
+                  requests=len(batch.requests)):
+            parts = self._split(batch.method, result, batch.sizes)
         if self._trace.enabled:
             # one span chain per request (tid = request id): how long it
             # coalesced, the shared engine execution, the host-side split
@@ -577,7 +588,8 @@ class QueryServer:
 
     def _obs_source(self) -> dict:
         """This server's section of ``repro.obs.snapshot()`` (JSON-able:
-        the non-finite percentile placeholders become None)."""
+        the non-finite percentile placeholders become None): each
+        method's ``ServerStats`` fields and its ``queue_ms`` histogram."""
 
         def clean(v):
             return None if (isinstance(v, float)
@@ -585,6 +597,9 @@ class QueryServer:
 
         out = {method: {k: clean(v) for k, v in s._asdict().items()}
                for method, s in self.stats().items()}
+        hists = self._obs.snapshot()["histograms"]
+        for method in self._stats:  # enqueue to execution, per request
+            out[method]["queue_ms"] = hists[f"serving.{method}.queue_ms"]
         out["admission"] = self.admission.stats()._asdict()
         return out
 

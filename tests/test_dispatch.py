@@ -14,7 +14,7 @@ import pytest
 
 from repro.core.dispatch import (ceil_to, concat_rows, device_mesh,
                                  make_plan, pad_leading, resolve_shards,
-                                 split_blocks)
+                                 take_block)
 
 
 # ---------------------------------------------------------------------------
@@ -101,7 +101,7 @@ def test_split_concat_roundtrip_identity():
         tree = {"a": jnp.asarray(rng.normal(size=(n, 3)).astype(np.float32)),
                 "b": jnp.arange(n, dtype=jnp.int32)}
         plan = make_plan(n, pad_multiple=4, chunk_size=chunk)
-        blocks = list(split_blocks(tree, plan))
+        blocks = [take_block(tree, plan, i) for i in range(plan.n_blocks)]
         assert len(blocks) == plan.n_blocks
         assert all(b["a"].shape[0] == plan.block for b in blocks)
         out = concat_rows(blocks, n)

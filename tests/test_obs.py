@@ -16,7 +16,12 @@ What this file pins, in rough order of importance:
   and > 0 over a fresh tracing.
 * **Engine metrics.**  Cache hits/misses, real vs padded rows (pad
   waste), chunk fan-out, and per-backend job counters match values
-  computable by hand from the plan.
+  computable by hand from the plan; with telemetry on, an engine call
+  still moves nothing from the device to the host.
+* **Spans on the profiler's clock.**  Under a profiler an engine call
+  opens ``engine.<method>`` around ``engine.prepare``, one
+  ``engine.block`` per block and ``engine.assemble``, in order; the
+  device programs' named scopes reach the compiled metadata.
 * **Serving back-compat.**  ``stats()`` still returns the pre-telemetry
   ``ServerStats`` shape (field set pinned), and the server appears as a
   named source in ``obs.snapshot()``.
@@ -27,8 +32,10 @@ What this file pins, in rough order of importance:
   (JSON ``null``), never a fake ``0.0``.
 """
 import gc
+import glob
 import json
 import math
+import os
 
 import jax
 import jax.numpy as jnp
@@ -38,6 +45,7 @@ import pytest
 from repro import obs
 from repro.api import Scene, VectorIndex, make_ray
 from repro.core import Triangle
+from repro.obs.device import DeviceTotal
 from repro.obs.metrics import HIST_BINS, MetricsRegistry
 from repro.obs.trace import TraceBuffer
 from repro.serving.query_server import ServerStats
@@ -214,8 +222,10 @@ def test_engine_metrics_pinned_against_plan(telemetry):
     assert delta("engine.rows.padded") == 32
     assert delta("engine.chunks") == 2
     assert delta("engine.calls.nearest.mxu") == 2
-    hist = obs.snapshot()["histograms"]["engine.call_ms.nearest"]
-    assert hist["count"] >= 2 and hist["min"] >= 0.0
+    # the engine no longer times calls itself (its engine.nearest span
+    # and the device trace do, without a block): no per-call histogram
+    assert not [h for h in obs.snapshot()["histograms"]
+                if h.startswith("engine.")]
 
     # snapshot's derived block agrees with its own counters
     snap = obs.snapshot()
@@ -240,6 +250,35 @@ def test_engine_job_counters_match_result(telemetry):
     assert (after.get("engine.jobs.triangle.wavefront", 0)
             - before.get("engine.jobs.triangle.wavefront", 0)
             ) == int(np.asarray(res.triangle_jobs).sum())
+
+
+def test_telemetry_on_moves_nothing_to_the_host(telemetry):
+    """``_obs_record`` neither blocks nor reads the device: the job totals
+    stay on the device until ``snapshot()``, and still equal the records'
+    sums there."""
+    engine = _scene_engine(pad_multiple=8, shard=1, chunk_size=8)
+    rays = _rays(20, seed=6)
+    engine.trace(rays, backend="wavefront")  # compile outside the guard
+    before = _counters()
+    with jax.transfer_guard_device_to_host("disallow"):
+        res = engine.trace(rays, backend="wavefront")
+        res2 = engine.trace(rays, backend="wavefront")
+    after = _counters()
+    for job, field in (("quadbox", "quadbox_jobs"),
+                       ("triangle", "triangle_jobs")):
+        key = f"engine.jobs.{job}.wavefront"
+        assert after[key] - before.get(key, 0) == (
+            int(np.asarray(getattr(res, field)).sum())
+            + int(np.asarray(getattr(res2, field)).sum()))
+
+
+def test_device_total_carries_past_32_bits():
+    total = DeviceTotal()
+    big = jnp.full((4,), 2**30 - 1, jnp.int32)  # 2^32 - 4 per addition
+    for _ in range(3):
+        total.add(big)
+    total.add(jnp.arange(5, dtype=jnp.int32))
+    assert int(total) == 3 * 4 * (2**30 - 1) + 10
 
 
 def test_engine_records_nothing_while_disabled():
@@ -279,6 +318,99 @@ assert snap["counters"]["engine.rows.real"] == 100
 assert snap["counters"]["engine.cache.hits"] == 1  # the telemetry-on call
 print("8dev telemetry parity OK")
 """, n_devices=8)
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock, named scopes
+# ---------------------------------------------------------------------------
+
+
+def _profiled(tmp_path, fn):
+    """``fn()`` under a CPU profiler trace; the engine's host spans from
+    the trace file, in order of opening."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    from jax.profiler import ProfileData
+    pb = glob.glob(os.path.join(tmp_path, "**", "*.xplane.pb"),
+                   recursive=True)[0]
+    return sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns)
+                   for p in ProfileData.from_file(pb).planes
+                   if p.name.startswith("/host:")
+                   for line in p.lines for e in line.events
+                   if e.name.startswith("engine.")),
+                  key=lambda s: (s[1], -s[2]))
+
+
+def test_engine_spans_nest_in_order_under_a_profiler(tmp_path):
+    rng = np.random.default_rng(3)
+    engine = VectorIndex.from_database(jnp.asarray(
+        rng.normal(size=(64, 16)).astype(np.float32))).engine(
+            pad_multiple=8, shard=1, chunk_size=8)
+    q = jnp.asarray(rng.normal(size=(30, 16)).astype(np.float32))
+    jax.block_until_ready(engine.nearest(q, 5))
+    spans = _profiled(tmp_path, lambda: jax.block_until_ready(
+        engine.nearest(q, 5)))
+    assert [s[0] for s in spans] == (["engine.nearest", "engine.prepare"]
+                                     + ["engine.block"] * 4
+                                     + ["engine.assemble"])
+    outer, inner = spans[0], spans[1:]
+    assert all(outer[1] <= s[1] and s[2] <= outer[2] for s in inner)
+    assert all(a[2] <= b[1] for a, b in zip(inner, inner[1:]))
+    # the same spans, with their args, in memory
+    kept = obs.profiled_spans()[-7:]
+    assert [s.name for s in kept] == (["engine.prepare"]
+                                      + ["engine.block"] * 4
+                                      + ["engine.assemble",
+                                         "engine.nearest"])
+    assert [s.args["rows"] for s in kept[1:5]] == [8, 8, 8, 6]
+
+
+def test_spans_are_kept_only_while_a_profiler_records():
+    n = len(obs.profiled_spans())
+    with obs.span("engine.test", index=1):
+        pass
+    assert len(obs.profiled_spans()) == n
+
+
+def test_op_scopes_name_the_wavefront_and_nearest_stages():
+    engine = _scene_engine(pad_multiple=8, shard=1)
+    jax.block_until_ready(engine.trace(_rays(9, seed=2),
+                                       backend="wavefront"))
+    rng = np.random.default_rng(4)
+    index = VectorIndex.from_database(jnp.asarray(
+        rng.normal(size=(64, 16)).astype(np.float32))).engine(
+            pad_multiple=8, shard=1)
+    jax.block_until_ready(index.nearest(
+        jnp.asarray(rng.normal(size=(8, 16)).astype(np.float32)), 3))
+    from jax._src import test_util as jtu
+    with jtu.count_jit_and_pmap_lowerings() as lowered, \
+            jtu.count_jit_compilation_cache_miss() as compiled:
+        paths = [p for ops in obs.programs.op_scopes().values()
+                 for p in ops.values()]
+    stages = {part for p in paths for part in p.split("/")}
+    assert {"wavefront.pop", "wavefront.box", "wavefront.triangle",
+            "wavefront.push", "nearest.distance",
+            "nearest.topk"} <= stages
+    # the jit caches hold every noted program: nothing is built again
+    assert lowered() == 0 and compiled() == 0
+
+
+def test_dropped_engine_drops_out_of_op_scopes():
+    rng = np.random.default_rng(5)
+    engine = VectorIndex.from_database(jnp.asarray(
+        rng.normal(size=(48, 4)).astype(np.float32))).engine(
+            pad_multiple=8, shard=1)
+    engine.nearest(jnp.asarray(rng.normal(size=(8, 4)).astype(
+        np.float32)), 2)
+    fn = next(iter(engine._cache.values()))
+    assert id(fn) in obs.programs._NOTED
+    key = id(fn)
+    del engine, fn
+    gc.collect()
+    assert key not in obs.programs._NOTED
 
 
 # ---------------------------------------------------------------------------
@@ -408,6 +540,8 @@ def test_server_counts_with_global_telemetry_off(tmp_path):
     assert name in snap["sources"]
     section = snap["sources"][name]
     assert section["nearest"]["requests"] == 4
+    queued = section["nearest"]["queue_ms"]  # enqueue -> execute, each
+    assert queued["count"] == 4 and queued["min"] >= 0.0
     assert "admission" in section
     json.dumps(snap)  # the whole snapshot must be strictly JSON-able
 
