@@ -196,9 +196,17 @@ def point_box_test(point: jax.Array, boxes: Box) -> PointBoxResult:
 # ---------------------------------------------------------------------------
 
 
-def _gather_dim(v: jax.Array, k: jax.Array) -> jax.Array:
-    """v: (..., 3), k: (...,) int -> v[..., k] elementwise over the batch."""
-    return jnp.take_along_axis(v, k[..., None], axis=-1)[..., 0]
+def _pick_axis(v: jax.Array, k: jax.Array) -> jax.Array:
+    """v: (..., 3), k: (...,) int -> v[..., k] elementwise over the batch.
+
+    A three-way select over the three static slices, not a per-lane gather:
+    a select returns one of its operands unchanged, so the result is bit for
+    bit ``take_along_axis`` (NaN, +-inf, -0.0 and subnormals included), and
+    it lowers to elementwise selects where the gather lowered to a general
+    gather.  ``make_ray`` only produces ``kx/ky/kz`` in {0, 1, 2}, so "else
+    axis 2" covers no other index.
+    """
+    return jnp.where(k == 0, v[..., 0], jnp.where(k == 1, v[..., 1], v[..., 2]))
 
 
 def ray_triangle_test(ray: Ray, tri: Triangle) -> TriangleResult:
@@ -216,9 +224,9 @@ def ray_triangle_test(ray: Ray, tri: Triangle) -> TriangleResult:
     b = tri.b - ray.origin
     c = tri.c - ray.origin
 
-    a_kx, a_ky, a_kz = (_gather_dim(a, ray.kx), _gather_dim(a, ray.ky), _gather_dim(a, ray.kz))
-    b_kx, b_ky, b_kz = (_gather_dim(b, ray.kx), _gather_dim(b, ray.ky), _gather_dim(b, ray.kz))
-    c_kx, c_ky, c_kz = (_gather_dim(c, ray.kx), _gather_dim(c, ray.ky), _gather_dim(c, ray.kz))
+    a_kx, a_ky, a_kz = (_pick_axis(a, ray.kx), _pick_axis(a, ray.ky), _pick_axis(a, ray.kz))
+    b_kx, b_ky, b_kz = (_pick_axis(b, ray.kx), _pick_axis(b, ray.ky), _pick_axis(b, ray.kz))
+    c_kx, c_ky, c_kz = (_pick_axis(c, ray.kx), _pick_axis(c, ray.ky), _pick_axis(c, ray.kz))
 
     # stage 3: shear products (9 multipliers)
     ax_s = sx * a_kz

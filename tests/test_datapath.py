@@ -1,11 +1,12 @@
 """The paper's special-case suite: 20 hand-constructed ray/box/triangle
 cases exercising the edge behaviour the RTL is designed for (§I: "twenty
 special ray-box/ray-triangle test cases"), plus Table VII stage semantics."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import (Box, Triangle, make_ray, quadsort,
+from repro.core import (Box, Ray, Triangle, make_ray, quadsort,
                         ray_box_test, ray_triangle_test)
 
 
@@ -209,3 +210,89 @@ def test_extent_not_applied_inside_datapath():
     qb = ray_box_test(ray((-10, .5, .5), (1, 0, 0), extent=1.0), unit4())
     # still reports the geometric intersection at t=10
     assert bool(qb.is_intersect[0, 0]) and np.isclose(qb.tmin[0, 0], 10.0)
+
+
+# ---- the sheared-axis pick of OpTriangle -------------------------------------
+
+#: every class of f32: NaN, +-inf, +-0.0, subnormals, normals of both signs
+SPECIAL = np.array([np.nan, np.inf, -np.inf, 0.0, -0.0, 1e-45, -1e-45,
+                    1.1754942e-38, -3e-39, 1.0, -2.5, 3.4e38],
+                   np.float32)
+
+
+def _gather_dim(v, k):
+    """The per-lane gather the select replaced, kept as the reference."""
+    return jnp.take_along_axis(v, k[..., None], axis=-1)[..., 0]
+
+
+def _bits(x):
+    return np.asarray(x).view(np.uint32)
+
+
+def _lanes(rays, width):
+    """An (R,) ray batch shared across ``width`` lanes, as the wavefront
+    hands it to OpTriangle: (R, width)."""
+    return Ray(*[jnp.broadcast_to(f[:, None], (f.shape[0], width) + f.shape[1:])
+                 for f in rays])
+
+
+@pytest.mark.parametrize("k", [0, 1, 2])
+def test_pick_axis_bitmatches_take_along_axis(k):
+    """The select returns one operand unchanged: every bit pattern of the
+    picked axis comes through, whatever the other two axes hold."""
+    from repro.core.datapath import _pick_axis
+
+    n = SPECIAL.size
+    v = np.stack(np.meshgrid(SPECIAL, SPECIAL, SPECIAL, indexing="ij"),
+                 axis=-1).reshape(n ** 3, 3)
+    kk = jnp.full((n ** 3,), k, jnp.int32)
+    got = _pick_axis(jnp.asarray(v), kk)
+    want = _gather_dim(jnp.asarray(v), kk)
+    np.testing.assert_array_equal(_bits(got), _bits(want))
+    np.testing.assert_array_equal(_bits(got), v[:, k].view(np.uint32))
+
+
+def test_triangle_test_bitmatches_gather_formulation(monkeypatch):
+    """OpTriangle with the select against the same stages with the old
+    per-lane gather: t_num, t_denom and hit bit for bit on an (R, 4) batch
+    whose rays lie on both sides of the ``dir[kz] < 0`` swap."""
+    from repro.core import datapath
+
+    rng = np.random.default_rng(16)
+    r, width = 512, 4
+    origin = rng.uniform(-1, 1, (r, 3)).astype(np.float32)
+    direction = rng.normal(size=(r, 3)).astype(np.float32)
+    rays = make_ray(jnp.asarray(origin), jnp.asarray(direction))
+    dir_kz = np.take_along_axis(direction, np.asarray(rays.kz)[:, None], 1)
+    assert (dir_kz < 0).any() and (dir_kz > 0).any()
+    rays = _lanes(rays, width)
+    # triangles around points on the rays, so a good share of lanes hit
+    t = rng.uniform(0.5, 3, (r, width, 1)).astype(np.float32)
+    centre = origin[:, None] + t * direction[:, None]
+    a, b, c = (jnp.asarray(centre + rng.normal(scale=0.5, size=(r, width, 3))
+                           .astype(np.float32)) for _ in range(3))
+    tris = Triangle(a=a, b=b, c=c)
+
+    # op by op, not jitted: XLA's CPU backend contracts a fused multiply
+    # and add into one FMA, and which pairs it fuses differs between the
+    # two programs, so a jitted pair can differ in the last bit for reasons
+    # that are not the pick's
+    got = ray_triangle_test(rays, tris)
+    monkeypatch.setattr(datapath, "_pick_axis", _gather_dim)
+    want = datapath.ray_triangle_test(rays, tris)
+    np.testing.assert_array_equal(_bits(got.t_num), _bits(want.t_num))
+    np.testing.assert_array_equal(_bits(got.t_denom), _bits(want.t_denom))
+    np.testing.assert_array_equal(np.asarray(got.hit), np.asarray(want.hit))
+    assert 0 < np.asarray(got.hit).sum() < r * width
+
+
+def test_triangle_test_lowers_without_gather():
+    """The sheared-axis pick stays elementwise: OpTriangle on an (R, 4)
+    batch lowers to no gather, which on the TPU took most of a frame."""
+    r, width = 256, 4
+    rays = make_ray(jnp.ones((r, 3)), jnp.ones((r, 3)))
+    rays = _lanes(rays, width)
+    v = jnp.zeros((r, width, 3))
+    text = jax.jit(ray_triangle_test).lower(rays, Triangle(v, v, v)).as_text()
+    assert "select" in text
+    assert "gather" not in text
