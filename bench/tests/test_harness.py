@@ -5,6 +5,7 @@ from __future__ import annotations
 
 import json
 import re
+import shutil
 from pathlib import Path
 
 import jax
@@ -12,17 +13,30 @@ import jax.numpy as jnp
 import pytest
 
 from bench import run
-from bench.tests.conftest import REPO, SERVED, SERVED_ENTRIES
+from bench.tests.conftest import (REPO, SERVED, TINY, apply_cuts,
+                                  cut_files, unknown_keys, with_stand_ins)
 from repro.api import QueryEngine
 
 SPEC = json.loads((REPO / "BENCHMARK.json").read_text())
 CELLS = [w["name"] for w in SPEC["workloads"]]
-#: every cell, with the served one that the tiny checkout adds
-ALL = CELLS + [SERVED]
-TINY_SPEC = dict(SPEC, **{g: SPEC[g] + e for g, e in SERVED_ENTRIES.items()})
+#: BENCHMARK.json with the stand-in entries that it lacks, as the tiny
+#: checkout holds it, and every cell of that
+TINY_SPEC = with_stand_ins(SPEC)
+ALL = [w["name"] for w in TINY_SPEC["workloads"]]
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SEED = 2**33 + 7
+
+
+def _mix(cell: str) -> dict:
+    traffic = next(w["traffic"] for w in TINY_SPEC["workloads"]
+                   if w["name"] == cell)
+    return json.loads((REPO / "bench" / "mixes"
+                       / f"{traffic}.json").read_text())
+
+
+#: the cells whose one caller waits for each call
+CLOSED = [cell for cell in ALL if _mix(cell)["loop"] == "closed"]
 
 
 def _e2e(cell: str, spec: dict = TINY_SPEC) -> set:
@@ -91,6 +105,56 @@ def test_every_name_finds_its_file():
         assert set(loaded.config["limits"])
         for m in loaded.metrics("end_to_end") + loaded.metrics("per_layer"):
             assert callable(loaded.reader(m["name"]))
+
+
+# ---------------------------------------------------------------------------
+# the size cuts of the CPU rehearsal
+# ---------------------------------------------------------------------------
+
+CUTS = cut_files(TINY_SPEC)
+
+
+@pytest.mark.parametrize("real", sorted(map(str, CUTS)))
+def test_every_named_file_has_a_cut_of_keys_it_holds(real):
+    cut = REPO / CUTS[Path(real)]
+    assert cut.exists(), f"{real} has no size cut at {cut}"
+    changes = json.loads(cut.read_text())
+    assert changes
+    assert not unknown_keys(changes, json.loads((REPO / real).read_text()))
+
+
+@pytest.mark.parametrize("real", sorted(map(str, CUTS)))
+def test_a_file_with_no_cut_is_refused(tiny_root, real):
+    cut = CUTS[Path(real)]
+    (tiny_root / cut).unlink()
+    with pytest.raises(FileNotFoundError, match=re.escape(str(cut))):
+        apply_cuts(tiny_root)
+
+
+@pytest.mark.parametrize("real", sorted(map(str, CUTS)))
+def test_a_key_renamed_in_its_file_does_not_pass_at_full_size(tiny_root,
+                                                              real):
+    """The first key that the cut changes, renamed in the real file: the
+    full-size value under the new name would reach the CPU uncut."""
+    key = next(iter(json.loads((REPO / CUTS[Path(real)]).read_text())))
+    data = json.loads((REPO / real).read_text())
+    data[key + "_renamed"] = data.pop(key)
+    (tiny_root / real).write_text(json.dumps(data))
+    with pytest.raises(KeyError, match=re.escape(key)):
+        apply_cuts(tiny_root)
+
+
+def test_cuts_once_applied_change_nothing_more(tiny_root):
+    before = {f: (tiny_root / f).read_text() for f in CUTS}
+    apply_cuts(tiny_root)
+    assert {f: (tiny_root / f).read_text() for f in CUTS} == before
+
+
+def test_stand_ins_already_in_benchmark_json_are_not_added_again():
+    names = [w["name"] for w in TINY_SPEC["workloads"]]
+    assert SERVED in names and len(names) == len(set(names))
+    assert with_stand_ins(TINY_SPEC) == TINY_SPEC
+    assert ALL == names and set(CELLS) <= set(ALL)
 
 
 def test_peaks_are_keyed_by_device_kind_with_a_source():
@@ -197,6 +261,53 @@ def test_a_new_cell_needs_only_entries_and_files(tiny_root, cpu_chip):
     assert out["metrics"]["wavefront.rounds_per_call"]["value"] > 0
 
 
+def test_a_new_configuration_needs_only_entries_and_files(tiny_root,
+                                                          cpu_chip):
+    """A later configuration, as a PR would add it: a full-size
+    configuration file and a full-size mix, the cut of each under
+    ``bench/tests/tiny/``, and entries in BENCHMARK.json.  The code that
+    cuts every cell cuts these; no file that is there is changed."""
+    config = json.loads((REPO / "bench" / "configs"
+                         / "sift-1m.json").read_text())
+    config["name"] = "cloud-x"
+    (tiny_root / "bench" / "configs" / "cloud-x.json").write_text(
+        json.dumps(config))
+    mix = json.loads((REPO / "bench" / "mixes" / "batch.json").read_text())
+    mix.update(rows_per_call=5000, sets=2)
+    (tiny_root / "bench" / "mixes" / "halves.json").write_text(
+        json.dumps(mix))
+    tiny = tiny_root / TINY
+    shutil.copyfile(tiny / "configs" / "sift-1m.json",
+                    tiny / "configs" / "cloud-x.json")
+    (tiny / "mixes" / "halves.json").write_text(json.dumps(
+        {"rows_per_call": 200, "sets": 2, "check_rows": 400}))
+    cell = "cloud-x.halves"
+    spec = json.loads((tiny_root / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "cloud-x", "source": "x",
+                            "file": "bench/configs/cloud-x.json",
+                            "reduced": [], "why": "x"})
+    spec["workloads"].append({"name": cell, "config": "cloud-x",
+                              "traffic": "halves", "chips": 1, "why": "x"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if m["name"] in ("queries_per_s", "nearest_roofline"):
+            m["workloads"].append(cell)
+    (tiny_root / "BENCHMARK.json").write_text(json.dumps(spec))
+
+    apply_cuts(tiny_root)
+    loaded = run.Cell(tiny_root, cell)
+    assert loaded.config["points"] < config["points"]
+    assert loaded.mix["rows_per_call"] == 200
+    out = run.run(tiny_root, cell, SEED, 0.3, False)
+    assert out["correct"] is True, out["checks"]
+    assert set(out["metrics"]) == {"queries_per_s", "setup_s"}
+    out = run.run(tiny_root, cell, SEED, 0.3, True)
+    assert out["correct"] is True, out["checks"]
+    assert set(out["metrics"]) == {"nearest_roofline"}
+    out = run.run(tiny_root, cell, SEED, 1e-9, False, control=True)
+    assert [n for n, v in out["control"].items()
+            if not v <= loaded.config["limits"][n]], out["control"]
+
+
 # ---------------------------------------------------------------------------
 # the comparison: its control fails, and so does each fault
 # ---------------------------------------------------------------------------
@@ -207,7 +318,7 @@ def _failed(checks: dict) -> list:
             if c["value"] is None or not c["value"] <= c["limit"]]
 
 
-@pytest.mark.parametrize("cell", ["frame-1m.primary", "sift-1m.batch"])
+@pytest.mark.parametrize("cell", CLOSED)
 def test_control_in_lower_precision_fails_the_comparison(tiny_root,
                                                          cpu_chip, cell):
     out = run.run(tiny_root, cell, SEED, 0.3, False, control=True)
@@ -219,11 +330,15 @@ def test_control_in_lower_precision_fails_the_comparison(tiny_root,
 
 def _alter_one(res):
     """One answer altered where it is produced: the first hit ray names
-    the next triangle, or the first query's nearest id the next id."""
+    the next triangle, or the first query's nearest id the next id.  A
+    record with neither is an error, not a fault that passes unplanted."""
     if hasattr(res, "tri_index"):
         i = jnp.argmax(res.hit)
         return res._replace(tri_index=res.tri_index.at[i].add(1))
-    return res._replace(indices=res.indices.at[0, 0].add(1))
+    if hasattr(res, "indices"):
+        return res._replace(indices=res.indices.at[0, 0].add(1))
+    raise TypeError(f"no answer to alter in a {type(res).__name__}: give "
+                    f"_alter_one the field that this kind answers with")
 
 
 def _drop_half(res):
@@ -251,15 +366,24 @@ def test_a_broken_timed_path_comes_out_incorrect(tiny_root, cpu_chip,
 
     monkeypatch.setattr(QueryEngine, method, broken)
     # one call of a closed loop, so that every row of it is compared
-    seconds = 1e-9 if "served" not in cell else 0.5
+    seconds = 1e-9 if cell in CLOSED else 0.5
     out = run.run(tiny_root, cell, SEED, seconds, False)
     assert out["correct"] is False
     assert _failed(out["checks"])
 
 
-def test_run_is_repeatable_from_its_seed(tiny_root, cpu_chip):
-    a = run.run(tiny_root, "sift-1m.batch", 5, 1e-9, False)
-    b = run.run(tiny_root, "sift-1m.batch", 5, 1e-9, False)
+def test_alter_one_refuses_a_record_with_no_answer_it_knows():
+    from collections import namedtuple
+
+    with pytest.raises(TypeError, match="no answer to alter"):
+        _alter_one(namedtuple("Neighbours", "ids dists")(
+            jnp.zeros((2, 3), jnp.int32), jnp.zeros((2, 3))))
+
+
+@pytest.mark.parametrize("cell", CLOSED)
+def test_run_is_repeatable_from_its_seed(tiny_root, cpu_chip, cell):
+    a = run.run(tiny_root, cell, 5, 1e-9, False)
+    b = run.run(tiny_root, cell, 5, 1e-9, False)
     assert a["checks"] == b["checks"]
 
 
