@@ -1,0 +1,11 @@
+"""Share of the nearest calls' device time in the neighbour search's leaf
+stage: the operations under the ``neighbor.leaf`` named scope (the
+candidate fetch, the leaf distances and the top-k insertion beats,
+``core/neighbor.py``) over the device-busy time inside the calls'
+``bench.call`` spans, in percent.  A program without the scope reads 0
+there and gives no reading."""
+from bench.scopes import call_share
+
+
+def read(ctx):
+    return call_share(ctx, "neighbor.leaf") or None

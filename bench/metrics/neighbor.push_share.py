@@ -1,0 +1,10 @@
+"""Share of the nearest calls' device time in the neighbour search's push
+stage: the operations under the ``neighbor.push`` named scope (the pruned
+far-to-near stack push and the job counts, ``core/neighbor.py``) over the
+device-busy time inside the calls' ``bench.call`` spans, in percent.  A
+program without the scope reads 0 there and gives no reading."""
+from bench.scopes import call_share
+
+
+def read(ctx):
+    return call_share(ctx, "neighbor.push") or None

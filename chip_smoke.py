@@ -298,12 +298,13 @@ def phase_cloud(seed: int, n_points: int, n_queries: int, k: int,
     brute = cloud.index
     r_sq = radius * radius
     # Two brute references over every point, no tree.  The leaf test's own
-    # arithmetic (core/neighbor.leaf_dist_sq): the traversal must match it
-    # exactly.  The core/knn matmul oracle contracts q.c in another order,
-    # so the two may disagree on points within a few ulps of the radius
-    # (DESIGN.md §9): counts must lie inside that band of it.
+    # difference form (core/neighbor.leaf_dist_sq): the traversal must
+    # match it exactly.  The core/knn matmul oracle scores in the expanded
+    # form, which rounds by up to ~eps * (|q|^2 + |c|^2), so the two may
+    # disagree on points inside that band of the radius (DESIGN.md §9):
+    # counts must lie inside that band of it.
     leaf = np.asarray(jax.jit(jax.vmap(
-        lambda p: leaf_dist_sq(p, brute.database, brute.sq_norms)))(q))
+        lambda p: leaf_dist_sq(p, brute.database)))(q))
     oracle = np.asarray(pairwise_scores(q, brute.database, "euclidean",
                                         c_sq_norms=brute.sq_norms))
     band = 4.0 * np.finfo(np.float32).eps * np.asarray(
@@ -326,12 +327,17 @@ def phase_cloud(seed: int, n_points: int, n_queries: int, k: int,
     check_ranks(leaf, np.where(w_in, within.scores, ref_scores),
                 np.where(w_in, within.indices, ref_idx), ref_scores,
                 ref_idx, band)
+    # nearest: the leaf form's own top-k, to the ulp that two programs may
+    # round a sum differently in (ids may swap only between such ties)
+    check_ranks(leaf, near.scores[:n_check], near.indices[:n_check],
+                ref_scores, ref_idx, 2.0 * np.spacing(ref_scores[:, -1]))
     ref_scores, ref_idx = brute.knn(q, k)
     max_diff, swaps = check_ranks(oracle, near.scores[:n_check],
                                   near.indices[:n_check], ref_scores,
                                   ref_idx, band)
     report("cloud.check", oracle="brute leaf form + core/knn", rows=n_check,
            counts="exact vs leaf form", within_masks="exact vs leaf form",
+           knn="leaf form to 2 ulp",
            counts_off_knn_at_boundary=boundary,
            knn_max_abs_score_diff=max_diff, knn_tie_swaps=swaps,
            result="pass")

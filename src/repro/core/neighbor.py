@@ -23,14 +23,23 @@ arithmetic is bit-identical by construction.
 
 Oracle contract
 ---------------
-The brute-force :mod:`repro.core.knn` path stays the bit-level oracle
-for the in-radius set: :func:`leaf_dist_sq` reproduces the MXU scoring
-form ``max(||q||^2 - 2 q.c + ||c||^2, 0)`` term-for-term, so the leaf
-acceptance test ``d^2 <= r^2`` is the *same float comparison* the oracle
-makes.  Node pruning, by contrast, uses geometric box distance — a
-different computation — so the pruning bound carries conservative slack
-(:data:`PRUNE_SLACK`): a too-loose bound only costs extra visits, never
-a missed in-radius point.
+:func:`leaf_dist_sq` computes the squared distance in the difference
+form ``(g0*g0 + g1*g1) + g2*g2`` with ``g = c - q``: at metre
+coordinates, with neighbours centimetres apart, ``c - q`` is exact in
+f32 and the form keeps full relative precision, where the expanded form
+``||q||^2 - 2 q.c + ||c||^2`` cancels away more than the distances it
+ranks.  It is associated as :func:`~repro.core.datapath.point_box_test`
+is, so a point leaf and its degenerate box give the same f32 number, and
+f32 rounding being monotone, every enclosing box's distance is at most
+its point's: pruning on box distance against the running bound never
+drops a point the leaf test would accept, with no slack.
+
+So the tree is exact against a brute pass in its own difference form:
+the same in-radius sets and counts, and the same k nearest up to ties.
+Against the brute MXU path (:mod:`repro.core.knn`, the expanded form) it
+agrees within that form's rounding band, about ``eps * (||q||^2 +
+||c||^2)``: narrow at unit scale, wider than the distances themselves at
+LiDAR coordinates.
 """
 from __future__ import annotations
 
@@ -45,14 +54,6 @@ from .traversal import STACK_SIZE
 from .types import Ray, make_ray
 
 NEIGHBOR_MODES = ("within", "nearest")
-
-#: Relative + scaled-absolute slack on the node-pruning bound.  The brute
-#: MXU form loses ~eps * (||q||^2 + ||c||^2) to cancellation, so a point
-#: the oracle counts as in-radius can have geometric box distance a hair
-#: *above* r^2.  bound = b*(1+S) + S*||q||^2 with S = 1e-5 >> f32 eps
-#: covers that gap with orders of magnitude to spare; the cost is a few
-#: extra node visits near the boundary, never a correctness loss.
-PRUNE_SLACK = 1e-5
 
 
 class NeighborRecord(NamedTuple):
@@ -82,18 +83,17 @@ def point_queries(points: jax.Array, radius=None) -> Ray:
     return make_ray(points, direction, extent)
 
 
-def leaf_dist_sq(p: jax.Array, pts: jax.Array,
-                 p_sq_norms: jax.Array) -> jax.Array:
-    """Query-to-candidate squared distances in the oracle's exact form.
+def leaf_dist_sq(p: jax.Array, pts: jax.Array) -> jax.Array:
+    """Query-to-candidate squared distances in the difference form.
 
-    p: (..., 3) queries; pts: (..., 4, 3) candidates; p_sq_norms:
-    (..., 4) precomputed ``||c||^2``.  This is term-for-term the brute
-    path's MXU expression ``max(||q||^2 - 2 q.c + ||c||^2, 0)`` so tree
-    leaf acceptance and the oracle make the *same float comparison*.
+    p: (..., 3) queries; pts: (..., 4, 3) candidates.  Per axis ``g = c -
+    q``, then ``(g0*g0 + g1*g1) + g2*g2``: the association of
+    :func:`~repro.core.datapath.point_box_test`, so a candidate and its
+    degenerate leaf box give the same f32 distance.
     """
-    q2 = jnp.sum(p * p, axis=-1)
-    qc = jnp.sum(p[..., None, :] * pts, axis=-1)
-    return jnp.maximum(q2[..., None] - 2.0 * qc + p_sq_norms, 0.0)
+    g = pts - p[..., None, :]
+    sq = g * g
+    return (sq[..., 0] + sq[..., 1]) + sq[..., 2]
 
 
 def insert_sorted(best_d: jax.Array, best_i: jax.Array, d: jax.Array,
@@ -116,31 +116,26 @@ def insert_sorted(best_d: jax.Array, best_i: jax.Array, d: jax.Array,
     return new_d, new_i
 
 
-def prune_bound(r_sq: jax.Array, kth_best: jax.Array, q_sq: jax.Array,
+def prune_bound(r_sq: jax.Array, kth_best: jax.Array,
                 mode: str) -> jax.Array:
     """Node-visit bound: a child is pushed iff its box distance is <= this.
 
     ``"within"`` prunes on the radius alone (every in-radius point must
     be found — the k-th best can't shrink the search).  ``"nearest"``
     additionally contracts to the current k-th best distance once the
-    list fills.  The slack term keeps the geometric bound conservative
-    w.r.t. the oracle's MXU-form arithmetic (see :data:`PRUNE_SLACK`);
-    the form ``b*(1+S) + S*q^2`` is inf-safe (no subtraction).
+    list fills.  No slack: a box's distance never exceeds its points'
+    leaf distances (see the module docstring).
     """
-    b = r_sq if mode == "within" else fmin(r_sq, kth_best)
-    return b * (1.0 + PRUNE_SLACK) + PRUNE_SLACK * q_sq
+    return r_sq if mode == "within" else fmin(r_sq, kth_best)
 
 
-def neighbor_wavefront(bvh: BVH4, sq_norms: jax.Array, queries: Ray,
-                       depth: int, k: int, mode: str = "within",
+def neighbor_wavefront(bvh: BVH4, queries: Ray, depth: int, k: int,
+                       mode: str = "within",
                        max_rounds: int | None = None) -> NeighborRecord:
     """Batch-level neighbor traversal (the wavefront engine's distance twin).
 
     ``bvh`` must be a point BVH (:func:`~repro.core.build.points.
-    build_point_bvh`): the cloud is read back as ``bvh.triangles.a`` and
-    ``sq_norms`` are its precomputed ``||c||^2`` (pass
-    ``knn.squared_norms(bvh.triangles.a)`` — derived from the *same*
-    array the tree holds, so refits can't serve stale norms).
+    build_point_bvh`): the cloud is read back as ``bvh.triangles.a``.
 
     ``queries`` carry the radius as ``extent`` (:func:`point_queries`);
     ``k``/``mode``/``max_rounds`` are static.  Like
@@ -162,7 +157,6 @@ def neighbor_wavefront(bvh: BVH4, sq_norms: jax.Array, queries: Ray,
     points = bvh.triangles.a
     p = queries.origin  # (R, 3)
     r_sq = queries.extent * queries.extent  # inf extent -> inf bound
-    q_sq = jnp.sum(p * p, axis=-1)
     n_q = p.shape[0]
     rows = jnp.arange(n_q, dtype=jnp.int32)
 
@@ -180,32 +174,35 @@ def neighbor_wavefront(bvh: BVH4, sq_norms: jax.Array, queries: Ray,
 
     def body(state):
         stack, sp, best_d, best_i, count, n_box, n_pt, rounds = state
-        active = sp > 0
-
-        # frontier pop (masked compaction, as in trace_wavefront)
-        node = jnp.where(active, stack[rows, jnp.maximum(sp - 1, 0)], 0)
-        sp = jnp.where(active, sp - 1, sp)
-        is_leaf_parent = node >= leaf_parent_offset
+        # named scopes label each stage's device operations in a profile,
+        # as in core/wavefront.py; they cost nothing at run time
+        with jax.named_scope("neighbor.pop"):
+            # frontier pop (masked compaction, as in trace_wavefront)
+            active = sp > 0
+            node = jnp.where(active, stack[rows, jnp.maximum(sp - 1, 0)], 0)
+            sp = jnp.where(active, sp - 1, sp)
+            is_leaf_parent = node >= leaf_parent_offset
 
         # ---- one batched point-box job over the whole frontier ----------
-        pb = point_box_test(p, child_boxes(bvh, node))
+        with jax.named_scope("neighbor.box"):
+            pb = point_box_test(p, child_boxes(bvh, node))
 
         # ---- batched point-distance round for the leaf-parent queries ---
-        leaf_pos = (4 * node[:, None] + 1 - leaf_offset
-                    + jnp.arange(4, dtype=jnp.int32))
-        leaf_pos = jnp.clip(leaf_pos, 0, bvh.leaf_tri.shape[0] - 1)
-        cand = bvh.leaf_tri[leaf_pos]  # (R, 4), -1 = padded leaf
-        safe = jnp.maximum(cand, 0)
-        d_sq = leaf_dist_sq(p, points[safe], sq_norms[safe])  # (R, 4)
-        in_r = (active[:, None] & is_leaf_parent[:, None]
-                & (cand >= 0) & (d_sq <= r_sq[:, None]))
-        count = count + jnp.sum(in_r, axis=1)
-        for c in range(4):  # static: 4 insertion beats per round
-            best_d, best_i = insert_sorted(
-                best_d, best_i, d_sq[:, c], cand[:, c], in_r[:, c])
+        with jax.named_scope("neighbor.leaf"):
+            leaf_pos = (4 * node[:, None] + 1 - leaf_offset
+                        + jnp.arange(4, dtype=jnp.int32))
+            leaf_pos = jnp.clip(leaf_pos, 0, bvh.leaf_tri.shape[0] - 1)
+            cand = bvh.leaf_tri[leaf_pos]  # (R, 4), -1 = padded leaf
+            d_sq = leaf_dist_sq(p, points[jnp.maximum(cand, 0)])  # (R, 4)
+            in_r = (active[:, None] & is_leaf_parent[:, None]
+                    & (cand >= 0) & (d_sq <= r_sq[:, None]))
+            count = count + jnp.sum(in_r, axis=1)
+            for c in range(4):  # static: 4 insertion beats per round
+                best_d, best_i = insert_sorted(
+                    best_d, best_i, d_sq[:, c], cand[:, c], in_r[:, c])
 
         # ---- push surviving children far-to-near ------------------------
-        bound = prune_bound(r_sq, best_d[k - 1], q_sq, mode)
+        bound = prune_bound(r_sq, best_d[k - 1], mode)
 
         def push_child(c, carry):
             stack, sp = carry
@@ -219,9 +216,10 @@ def neighbor_wavefront(bvh: BVH4, sq_norms: jax.Array, queries: Ray,
             sp = jnp.where(ok, sp + 1, sp)
             return stack, sp
 
-        stack, sp = jax.lax.fori_loop(0, 4, push_child, (stack, sp))
-        n_box = n_box + active.astype(jnp.int32)
-        n_pt = n_pt + jnp.where(active & is_leaf_parent, 4, 0)
+        with jax.named_scope("neighbor.push"):
+            stack, sp = jax.lax.fori_loop(0, 4, push_child, (stack, sp))
+            n_box = n_box + active.astype(jnp.int32)
+            n_pt = n_pt + jnp.where(active & is_leaf_parent, 4, 0)
         return stack, sp, best_d, best_i, count, n_box, n_pt, rounds + 1
 
     (_, _, best_d, best_i, count, n_box, n_pt, rounds) = jax.lax.while_loop(

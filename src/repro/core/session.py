@@ -171,11 +171,17 @@ class NearestResult(NamedTuple):
 
     ``valid`` masks the slots that hold a real neighbor — ``k`` is clamped
     to the database size, so with ``k > N`` the trailing slots carry the
-    pad convention (inf / -inf score, index -1) and ``valid`` is False."""
+    pad convention (inf / -inf score, index -1) and ``valid`` is False.
+
+    The tree routes also report their datapath work, as
+    :class:`TraceResult` does; the brute routes leave these ``None``."""
 
     scores: jax.Array  # (M, k) f32
     indices: jax.Array  # (M, k) i32
     valid: jax.Array  # (M, k) bool  which slots hold real neighbors
+    box_jobs: jax.Array | None = None  # (M,) i32  point-box jobs issued
+    point_jobs: jax.Array | None = None  # (M,) i32  point-distance jobs
+    rounds: jax.Array | None = None  # ()   i32  batch-level rounds
 
 
 class WithinResult(NamedTuple):
@@ -423,14 +429,7 @@ def _build_pallas_scores(index: "VectorIndex", metric: str, interpret):
     raise ValueError(f"unknown metric: {metric} (want one of {METRICS})")
 
 
-def _prepare_tree_wavefront(cloud: "PointCloudScene"):
-    """Derive the wavefront neighbor engine's ctx from the *runtime* BVH:
-    the ``||c||^2`` norms come from the same array the tree holds, so a
-    refit can never serve stale norms."""
-    return lambda bvh: (bvh, squared_norms(bvh.triangles.a))
-
-
-@register_neighbor_backend("tree_wavefront", prepare=_prepare_tree_wavefront)
+@register_neighbor_backend("tree_wavefront")
 def _build_tree_wavefront(cloud: "PointCloudScene", mode: str, k: int,
                           interpret=None):
     """Batch-level neighbor frontier loop (``core/neighbor.py``): the
@@ -438,9 +437,8 @@ def _build_tree_wavefront(cloud: "PointCloudScene", mode: str, k: int,
     apply)."""
     depth = cloud.depth
 
-    def run(ctx, rays):
-        bvh, sq = ctx
-        return neighbor_wavefront(bvh, sq, rays, depth, k=k, mode=mode)
+    def run(bvh, rays):
+        return neighbor_wavefront(bvh, rays, depth, k=k, mode=mode)
 
     return run
 
@@ -940,8 +938,12 @@ class QueryEngine:
         "auto" stays on the distance backends.  Otherwise the wavefront
         neighbor engine, on every platform (the fused ``tree_pallas``
         kernel does not lower under Mosaic, so it is explicit-only, like
-        the fused trace kernel).  Every route returns the same in-radius
-        sets and neighbor ranks, so the policy is pure scheduling."""
+        the fused trace kernel).  The tree routes are exact against a
+        brute pass in their own difference-form leaf distance; the brute
+        routes score in the expanded MXU form, so the two agree within
+        that form's rounding band, ``eps * (||q||^2 + ||c||^2)`` (DESIGN.md
+        §9): narrow at unit scale, wider than the distances themselves at
+        metre coordinates, where the tree route is the exact one."""
         if self.cloud is None or metric != "euclidean":
             return self.resolve_distance_backend()
         n = self.cloud.size
@@ -1437,9 +1439,11 @@ class QueryEngine:
 
         With a :class:`PointCloudScene`, ``backend="auto"`` routes
         euclidean queries through the BVH (``tree_wavefront``; the fused
-        ``tree_pallas`` is explicit-only) when the tree wins; the brute
-        backends (``mxu`` / ``pallas``) remain the rank-equivalent
-        oracle."""
+        ``tree_pallas`` is explicit-only) when the tree wins, and the
+        result then carries the walk's ``box_jobs`` / ``point_jobs`` /
+        ``rounds``.  The tree and the brute backends (``mxu`` /
+        ``pallas``) agree within the expanded form's rounding band (see
+        :meth:`resolve_neighbor_backend`)."""
         if metric not in METRICS:
             raise ValueError(f"unknown metric: {metric}")
         k = check_k(k)
@@ -1448,7 +1452,8 @@ class QueryEngine:
         if name in _NEIGHBOR_BACKENDS:
             rec = self._tree_neighbor("nearest", queries, k, None, name,
                                       shard=shard, chunk_size=chunk_size)
-            return NearestResult(rec.dist_sq, rec.index, rec.valid)
+            return NearestResult(rec.dist_sq, rec.index, rec.valid,
+                                 rec.box_jobs, rec.point_jobs, rec.rounds)
 
         def topk(s):
             scores, idx = select_topk(s, k, metric)
@@ -1472,7 +1477,8 @@ class QueryEngine:
         euclidean radius and on ``k <= 0``); ``k > N`` pads like
         :meth:`nearest`.  Routing is as in :meth:`nearest`: tree-backed
         for euclidean cloud queries when the tree wins, in-radius
-        membership bit-exact against the brute oracle either way."""
+        membership exact against a brute pass in the route's own
+        distance form."""
         if metric not in RADIUS_METRICS:
             raise ValueError(f"unknown radius metric: {metric}")
         radius = check_radius(radius, metric)

@@ -55,7 +55,6 @@ from jax.experimental import pallas as pl
 
 from ..core.bvh import BVH4, DatapathConfig, level_offset, resolve_config
 from ..core.datapath import point_box_test, ray_box_test, ray_triangle_test
-from ..core.knn import squared_norms
 from ..core.neighbor import (
     NEIGHBOR_MODES,
     NeighborRecord,
@@ -338,19 +337,16 @@ def traverse_packed(packed, rays: Ray, depth: int, *,
 # visits feed a running top-k insertion network instead of a best-hit
 # register.  The round body calls the same stage helpers as
 # `core/neighbor.neighbor_wavefront` (point_box_test, leaf_dist_sq,
-# insert_sorted, prune_bound), so both engines' leaf acceptance is the
-# brute oracle's exact float comparison.
+# insert_sorted, prune_bound), so both engines' leaf distances and
+# acceptance are the same float computation.
 
 
 def pack_point_bvh(bvh: BVH4):
     """Point BVH4 -> the neighbor kernel's resident operands.
 
     Node boxes and the leaf table pack exactly like :func:`pack_bvh`; the
-    cloud packs as 4 rows (x, y, z, ||c||^2) so each candidate gather
-    also lands the precomputed squared norm the oracle form needs.
-    Deriving the norms here — from the same array the tree holds — is
-    what keeps refit safe: re-packing a refit BVH can't serve stale
-    norms.
+    cloud packs as 3 rows (x, y, z), read from the same array the tree
+    holds, so re-packing a refit BVH serves the moved points.
     """
     n_nodes = bvh.node_lo.shape[0]
     nodes_pad = ceil_to(n_nodes, LANES)
@@ -360,9 +356,7 @@ def pack_point_bvh(bvh: BVH4):
     leaf = pad_cols(bvh.leaf_tri[None, :].astype(jnp.int32), leaf_pad, -1)
     pts = bvh.triangles.a  # the cloud (see core/build/points.py)
     pts_pad = ceil_to(pts.shape[0], LANES)
-    pt_rows = pad_cols(
-        jnp.concatenate([pts.T, squared_norms(pts)[None, :]], axis=0),
-        pts_pad)
+    pt_rows = pad_cols(pts.T, pts_pad)
     return nlo, nhi, leaf, pt_rows
 
 
@@ -375,11 +369,10 @@ def _neighbor_kernel(ray_ref, nlo_ref, nhi_ref, leaf_ref, pts_ref,
     node_lo = nlo_ref[...]  # (3, num_nodes_pad)
     node_hi = nhi_ref[...]
     leaf_tab = leaf_ref[0, :]  # (n_leaf_pad,) i32
-    pt_rows = pts_ref[...]  # (4, n_pts_pad): rows x | y | z | ||c||^2
+    pt_rows = pts_ref[...]  # (3, n_pts_pad): rows x | y | z
 
     p = ray.origin  # (L, 3): the query points
     r_sq = ray.extent * ray.extent  # inf extent -> inf bound
-    q_sq = jnp.sum(p * p, axis=-1)
 
     leaf_parent_offset = level_offset(depth - 1)
     leaf_offset = level_offset(depth)
@@ -420,9 +413,9 @@ def _neighbor_kernel(ray_ref, nlo_ref, nhi_ref, leaf_ref, pts_ref,
         leaf_pos = base[:, None] - leaf_offset + quad[None, :]
         leaf_pos = jnp.clip(leaf_pos, 0, n_leaf - 1)
         cand = jnp.take(leaf_tab, leaf_pos)  # (L, 4), -1 = padded leaf
-        pv = jnp.take(pt_rows, jnp.maximum(cand, 0), axis=1)  # (4, L, 4)
-        pts = jnp.moveaxis(pv[0:3], 0, -1)  # (L, 4, 3)
-        d_sq = leaf_dist_sq(p, pts, pv[3])  # oracle MXU form, (L, 4)
+        pv = jnp.take(pt_rows, jnp.maximum(cand, 0), axis=1)  # (3, L, 4)
+        pts = jnp.moveaxis(pv, 0, -1)  # (L, 4, 3)
+        d_sq = leaf_dist_sq(p, pts)  # difference form, (L, 4)
         in_r = (active[:, None] & is_leaf_parent[:, None]
                 & (cand >= 0) & (d_sq <= r_sq[:, None]))
         count = count + jnp.sum(in_r, axis=1)
@@ -431,7 +424,7 @@ def _neighbor_kernel(ray_ref, nlo_ref, nhi_ref, leaf_ref, pts_ref,
                 best_d, best_i, d_sq[:, c], cand[:, c], in_r[:, c])
 
         # ---- push surviving children far-to-near -------------------------
-        bound = prune_bound(r_sq, best_d[k - 1], q_sq, mode)
+        bound = prune_bound(r_sq, best_d[k - 1], mode)
         for c in range(4):
             slot = 3 - c  # farthest first, nearest ends on top
             ok = (active & ~is_leaf_parent

@@ -20,8 +20,9 @@ the query-side twin of the LM ``serving/engine.py``:
 * **dispatch** — one ``QueryEngine`` call per batch, on a worker thread
   so the event loop keeps admitting while the engine computes.
 * **split** — the response is handed back per request with the dispatch
-  layer's ``slice_rows`` (and, for traces, a per-request ``rounds``
-  re-reduction), delivered through asyncio futures.
+  layer's ``slice_rows`` (and, for traces and tree-routed nearest
+  queries, a per-request ``rounds`` re-reduction), delivered through
+  asyncio futures.
 
 **The bit-parity contract** (``tests/test_serving.py``): every response
 is bit-identical — hits, indices, scores, *and* job counters — to
@@ -531,22 +532,23 @@ class QueryServer:
     def _split(self, method: str, result, sizes):
         # split on the host for the same reason _assemble_payload builds
         # there: device slicing would compile per (shape, range) combo
-        rounds_dtype = None
-        if method == "trace":
-            rounds_dtype = jnp.asarray(result.rounds).dtype
+        # rounds is the one batch-coupled field (traces, and nearest on a
+        # tree route): re-reduce it per request.  A row is active for
+        # exactly as many consecutive rounds as it issued box jobs, so the
+        # request-level round count is the max over its own rows — the
+        # same invariant chunked dispatch already relies on
+        jobs = "quadbox_jobs" if method == "trace" else "box_jobs"
+        rounds = getattr(result, "rounds", None)
+        if rounds is not None:
+            rounds_dtype = jnp.asarray(rounds).dtype
             result = result._replace(rounds=None)
         host = jax.tree_util.tree_map(np.asarray, result)
         parts = [jax.tree_util.tree_map(jnp.asarray, p)
                  for p in slice_rows(host, sizes)]
-        if method == "trace":
-            # rounds is the one batch-coupled field: re-reduce it per
-            # request (a ray is active for exactly quadbox_jobs
-            # consecutive rounds, so the request-level round count is the
-            # max over its own rays — the same invariant chunked dispatch
-            # already relies on)
+        if rounds is not None:
             parts = [p._replace(rounds=jnp.asarray(
-                np.max(np.asarray(p.quadbox_jobs)), dtype=rounds_dtype))
-                for p in parts]
+                np.max(np.asarray(getattr(p, jobs))),
+                dtype=rounds_dtype)) for p in parts]
         return parts
 
     # -- observability -----------------------------------------------------

@@ -344,12 +344,11 @@ def test_fuzz_tree_neighbors_match_brute(seed, n, builder, q_seed, n_q,
 
     The two tree engines (and the chunked twin) share stage helpers and
     must bit-match each other, *job counters included*.  Against the
-    brute oracle the leaf test reuses the MXU arithmetic form, but its
-    ``q.c`` term is an elementwise sum rather than a HIGHEST-precision
-    ``jnp.dot`` — a ~1-ulp contraction difference — so membership is
-    compared exactly away from the radius boundary and left free inside
-    a +-tol band (deterministic-seed exactness lives in
-    ``test_neighbor.py``).
+    brute oracle the leaf test computes the difference form while the
+    oracle computes the expanded MXU form, which rounds by up to ~eps *
+    (|q|^2 + |c|^2) — so membership is compared exactly away from the
+    radius boundary and left free inside a +-tol band (exactness against
+    the leaf's own form lives in ``test_neighbor.py``).
     """
     cloud, engine, chunked = _cloud(seed, n, builder)
     rng = np.random.default_rng(q_seed)

@@ -1,19 +1,20 @@
 """Traversal-backed neighbor search: stage units, engine parity, the
-deterministic tree-vs-brute exactness contract, the auto policy, and the
-sharded + chunked scale acceptance run (DESIGN.md §9).
+deterministic tree-vs-brute exactness contract (at unit scale and at
+LiDAR coordinates), the auto policy, and the sharded + chunked scale
+acceptance run (DESIGN.md §9).
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.api import PointCloudScene, VectorIndex
-from repro.core import Box
+from repro.core import Box, neighbor
 from repro.core.build.points import build_point_bvh, refit_points
 from repro.core.bvh import level_offset
 from repro.core.datapath import point_box_test
-from repro.core.knn import squared_norms
-from repro.core.neighbor import (insert_sorted, neighbor_wavefront,
-                                 point_queries)
+from repro.core.neighbor import (insert_sorted, leaf_dist_sq,
+                                 neighbor_wavefront, point_queries)
 
 BUILDERS = ("lbvh", "sah")
 NEIGHBOR_FIELDS = ("dist_sq", "index", "valid", "count", "box_jobs",
@@ -153,8 +154,7 @@ def test_fused_bitmatches_wavefront(builder, mode):
     res = build_point_bvh(_pts(300, seed=8), builder=builder)
     rays = point_queries(_pts(70, seed=9),
                          0.8 if mode == "within" else None)
-    a = neighbor_wavefront(res.bvh, squared_norms(res.bvh.triangles.a),
-                           rays, res.depth, k=8, mode=mode)
+    a = neighbor_wavefront(res.bvh, rays, res.depth, k=8, mode=mode)
     b = neighbor_fused(res.bvh, rays, res.depth, 8, mode=mode)
     for f in NEIGHBOR_FIELDS:
         np.testing.assert_array_equal(np.asarray(getattr(a, f)),
@@ -170,13 +170,16 @@ def test_fused_bitmatches_wavefront(builder, mode):
 
 @pytest.mark.parametrize("backend", ("tree_wavefront", "tree_pallas"))
 def test_tree_matches_brute_exactly(backend):
+    """Exact against a brute pass in the leaf's own difference form; within
+    the expanded form's rounding band of the MXU oracle."""
     n, m = 500, 40
-    cloud = PointCloudScene.from_points(_pts(n, seed=10))
+    pts = _pts(n, seed=10)
+    cloud = PointCloudScene.from_points(pts)
     engine = cloud.engine(pad_multiple=8, shard=1)
     q = _pts(m, seed=11)
-    oracle = np.asarray(engine.scores(q, "euclidean", backend="mxu"))
+    leaf = np.asarray(leaf_dist_sq(q, pts))  # (m, n), no tree
     for radius in (0.3, 0.9):
-        inside = oracle <= radius * radius
+        inside = leaf <= radius * radius
         assert inside.sum(1).max() < n
         rec = engine.neighbor_search(q, n, radius=radius, backend=backend)
         w, idx = np.asarray(rec.valid), np.asarray(rec.index)
@@ -185,9 +188,146 @@ def test_tree_matches_brute_exactly(backend):
         np.testing.assert_array_equal(np.asarray(rec.count),
                                       inside.sum(1))
     near = engine.nearest(q, 7, backend=backend)
-    brute = engine.nearest(q, 7, backend="mxu")
     np.testing.assert_array_equal(np.asarray(near.indices),
-                                  np.asarray(brute.indices))
+                                  np.argsort(leaf, axis=1)[:, :7])
+    oracle = np.asarray(engine.scores(q, "euclidean", backend="mxu"))
+    brute = engine.nearest(q, 7, backend="mxu")
+    picked = np.take_along_axis(oracle, np.asarray(near.indices), 1)
+    band = 4 * np.finfo(np.float32).eps * (
+        np.sum(np.square(np.asarray(q)), 1, keepdims=True)
+        + np.max(np.sum(np.square(np.asarray(pts)), 1)))
+    assert (np.abs(picked - np.asarray(brute.scores)) <= band).all()
+
+
+# ---------------------------------------------------------------------------
+# LiDAR coordinates: metres from the origin, neighbours centimetres apart
+# ---------------------------------------------------------------------------
+
+
+def _lidar(n_patches=8, per_patch=256, n_q=64, seed=20):
+    """Patches of points with a 2 cm spread at coordinates up to 30 m from
+    the origin, and queries drawn the same way."""
+    rng = np.random.default_rng(seed)
+    centers = rng.uniform(-30.0, 30.0, (n_patches, 3))
+    pts = (np.repeat(centers, per_patch, axis=0)
+           + rng.normal(scale=0.02, size=(n_patches * per_patch, 3)))
+    q = (centers[rng.integers(0, n_patches, n_q)]
+         + rng.normal(scale=0.02, size=(n_q, 3)))
+    return pts.astype(np.float32), q.astype(np.float32)
+
+
+def _expanded_leaf_dist_sq(p, pts):
+    """The expanded form ``max(|q|^2 - 2 q.c + |c|^2, 0)``, which the leaf
+    computed before it took the difference form."""
+    q2 = jnp.sum(p * p, axis=-1)
+    qc = jnp.sum(p[..., None, :] * pts, axis=-1)
+    return jnp.maximum(q2[..., None] - 2.0 * qc
+                       + jnp.sum(pts * pts, axis=-1), 0.0)
+
+
+def _rows_off_exact_knn(pts, q, idx, k):
+    """Rows whose ``k`` reported ids are not the float64 brute top-k, where
+    a swap counts only between ids whose distances lie more than 2 ulp
+    (float32) from the k-th distance."""
+    d = np.sum(np.square(q[:, None, :].astype(np.float64)
+                         - pts[None].astype(np.float64)), axis=2)
+    want = np.argsort(d, axis=1)[:, :k]
+    kth = np.take_along_axis(d, want[:, -1:], axis=1)[:, 0]
+    tie = 2.0 * np.spacing(kth.astype(np.float32)).astype(np.float64)
+    off = 0
+    for i in range(len(q)):
+        got = set(idx[i].tolist())
+        if len(got) != k or min(got) < 0:
+            off += 1
+        elif any(abs(d[i, j] - kth[i]) > tie[i]
+                 for j in got ^ set(want[i].tolist())):
+            off += 1
+    return off
+
+
+@pytest.mark.parametrize("backend", ("tree_wavefront", "tree_pallas"))
+def test_lidar_scale_nearest_is_exact(backend):
+    pts, q = _lidar()
+    engine = PointCloudScene.from_points(jnp.asarray(pts)).engine(
+        pad_multiple=8, shard=1)
+    near = engine.nearest(jnp.asarray(q), 16, backend=backend)
+    assert _rows_off_exact_knn(pts, q, np.asarray(near.indices), 16) == 0
+
+
+def test_expanded_leaf_form_fails_at_lidar_scale(monkeypatch):
+    """The same data through the expanded form: its cancellation (about
+    eps * |q|^2, 1e-4 m^2 at 30 m) outweighs distances of a few cm^2."""
+    monkeypatch.setattr(neighbor, "leaf_dist_sq", _expanded_leaf_dist_sq)
+    pts, q = _lidar()
+    engine = PointCloudScene.from_points(jnp.asarray(pts)).engine(
+        pad_multiple=8, shard=1)
+    near = engine.nearest(jnp.asarray(q), 16, backend="tree_wavefront")
+    assert _rows_off_exact_knn(pts, q, np.asarray(near.indices),
+                               16) > len(q) // 10
+
+
+@pytest.mark.parametrize("jit", (False, True), ids=("eager", "jit"))
+def test_point_leaf_equals_its_degenerate_box(jit):
+    """Bit for bit: a candidate's leaf distance and the point-box distance
+    of its degenerate leaf box (lo == hi == the point)."""
+    pts, q = _lidar(n_patches=4, per_patch=128, n_q=128, seed=21)
+    c = jnp.asarray(pts.reshape(128, 4, 3))
+    q = jnp.asarray(q)
+
+    def box_dist(q, c):  # one candidate per call: no sort between slots
+        return jnp.stack([point_box_test(q, Box(lo=jnp.broadcast_to(
+            c[:, j:j + 1], c.shape), hi=jnp.broadcast_to(
+            c[:, j:j + 1], c.shape))).dist_sq[:, 0] for j in range(4)], 1)
+
+    leaf, box = ((jax.jit(leaf_dist_sq), jax.jit(box_dist)) if jit
+                 else (leaf_dist_sq, box_dist))
+    np.testing.assert_array_equal(
+        np.asarray(leaf(q, c)).view(np.uint32),
+        np.asarray(box(q, c)).view(np.uint32))
+
+
+def test_enclosing_box_never_farther_than_its_point():
+    """Every ancestor box's point-box distance is at most its point's leaf
+    distance in f32, so the pruning bound needs no slack."""
+    pts, q = _lidar(per_patch=64, n_q=32, seed=22)
+    res = build_point_bvh(jnp.asarray(pts))
+    bvh, depth = res.bvh, res.depth
+    lt = np.asarray(bvh.leaf_tri)
+    slots = np.flatnonzero(lt >= 0)
+    q = jnp.asarray(q)
+    d_pt = np.asarray(leaf_dist_sq(q, jnp.asarray(pts[lt[slots]])))
+    node = level_offset(depth) + slots
+    levels = 0
+    while True:
+        lo = jnp.broadcast_to(bvh.node_lo[node][None, :, None, :],
+                              (q.shape[0], len(node), 4, 3))
+        hi = jnp.broadcast_to(bvh.node_hi[node][None, :, None, :],
+                              lo.shape)
+        p = jnp.broadcast_to(q[:, None, :], (q.shape[0], len(node), 3))
+        d_box = np.asarray(point_box_test(p, Box(lo=lo, hi=hi)).dist_sq)
+        assert (d_box[..., 0] <= d_pt).all(), levels
+        levels += 1
+        if node[0] == 0:
+            break
+        node = (node - 1) // 4
+    assert levels == depth + 1
+
+
+def test_nearest_reports_the_tree_walks_counters():
+    pts, q = _lidar(n_q=16, seed=23)
+    engine = PointCloudScene.from_points(jnp.asarray(pts)).engine(
+        pad_multiple=8, shard=1)
+    q = jnp.asarray(q)
+    near = engine.nearest(q, 16, backend="tree_wavefront")
+    rec = engine.neighbor_search(q, 16, mode="nearest",
+                                 backend="tree_wavefront")
+    for f in ("box_jobs", "point_jobs", "rounds"):
+        np.testing.assert_array_equal(np.asarray(getattr(near, f)),
+                                      np.asarray(getattr(rec, f)),
+                                      err_msg=f)
+    assert int(near.rounds) == int(np.max(np.asarray(near.box_jobs)))
+    brute = engine.nearest(q, 16, backend="mxu")
+    assert (brute.box_jobs, brute.point_jobs, brute.rounds) == (None,) * 3
 
 
 def test_refit_reroutes_results():

@@ -231,6 +231,9 @@ def test_server_mixed_methods_bitparity(engine):
                      dict(ray_type=ray_type)))
     for i in range(4):
         jobs.append(("nearest", _queries(1 + i % 3, 80 + i), dict(k=5)))
+        # the tree route's records carry job counters and rounds too
+        jobs.append(("nearest", _queries(2 + i % 3, 60 + i),
+                     dict(k=5, backend="tree_wavefront")))
         jobs.append(("within", _queries(2 + i % 2, 90 + i),
                      dict(radius=1.0, k=6)))
         jobs.append(("count_within", _queries(3, 70 + i),
